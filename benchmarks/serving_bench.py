@@ -78,6 +78,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serving import (ArrivalSpec, ChunkedPrefillPolicy, DeadlinePolicy,
                            EncodeTask, FCFSPolicy, InferenceEngine, LoadSpec,
@@ -708,6 +709,7 @@ def check_goodput(rec: dict) -> list:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="phi4-mini-3.8b")
     ap.add_argument("--full", action="store_true",
@@ -935,7 +937,9 @@ def main(argv=None) -> int:
               f"{', ' + str(tr['trace_dropped']) + ' dropped' if tr['trace_dropped'] else ''}"
               f" -> {tr['trace_out'] or '(unwritten)'}")
         for ph, row in tr["phase_util"].items():
-            print(f"    {ph}: MFU {row['mfu']:.2e} MBU {row['mbu']:.2e} "
+            mfu, mbu = (("not measured",) * 2 if row["mfu"] is None
+                        else (f"{row['mfu']:.2e}", f"{row['mbu']:.2e}"))
+            print(f"    {ph}: MFU {mfu} MBU {mbu} "
                   f"({row['time_s'] * 1e3:.0f}ms, {row['tokens']:.0f} tok, "
                   f"{row['passes']:.0f} passes)")
         problems = check_spec(spec_rec)

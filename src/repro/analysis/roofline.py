@@ -1,4 +1,5 @@
-"""Three-term roofline model for TPU v5e from the dry-run's compiled HLO.
+"""Device peaks, and the three-term roofline model for TPU v5e from the
+dry-run's compiled HLO.
 
   compute term    = dtype-weighted dot FLOPs / per-chip peak
   memory term     = HBM-traffic proxy / per-chip HBM bandwidth
@@ -12,16 +13,38 @@ fraction = compute_term / max(all terms) is the MFU-style score (§Perf).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 from repro.analysis.hlo import HloSummary
 
-# TPU v5e hardware constants (assignment spec)
-PEAK_BF16 = 197e12          # FLOP/s per chip
-PEAK_FP8 = 394e12
-PEAK_FP32 = 98.5e12         # bf16 peak / 2 (fp32 via MXU passes)
-HBM_BW = 819e9              # B/s per chip
-ICI_BW = 50e9               # B/s per link (1-link conservative convention)
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    bf16_flops: float           # FLOP/s
+    int8_ops: float             # OP/s
+    hbm_bw: float               # B/s
+    ici_link_bw: float          # B/s per chip-to-chip link
+    source: str
+
+
+# The one peak table, keyed by `jax.Device.device_kind`.  A device that is
+# not listed has no peaks: utilizations against it are "not measured".
+PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bw=819e9,
+        ici_link_bw=1600e9 / 8 / 4,       # 1,600 Gbit/s over 4 links
+        source="Google Cloud documentation, 'TPU v5e' (per chip)"),
+}
+
+
+# The dry-run projects onto a TPU v5e pod whatever backend compiles it
+V5E = PEAKS["TPU v5 lite"]
+PEAK_BF16 = V5E.bf16_flops  # FLOP/s per chip
+PEAK_FP8 = V5E.int8_ops     # 8-bit MXU rate (v5e has int8, not fp8, units)
+PEAK_FP32 = PEAK_BF16 / 2   # fp32 via MXU passes
+HBM_BW = V5E.hbm_bw         # B/s per chip
+ICI_BW = V5E.ici_link_bw    # B/s per link (1-link conservative convention)
 
 _PEAK_BY_DTYPE = {
     "bf16": PEAK_BF16, "f16": PEAK_BF16, "f32": PEAK_FP32, "f64": PEAK_FP32 / 4,
@@ -104,17 +127,21 @@ def decoder_flops_per_token(cfg) -> float:
     return 2.0 * n
 
 
-def utilization(flops: float, mem_bytes: float, time_s: float, *,
-                peak_flops: float = PEAK_BF16,
-                hbm_bw: float = HBM_BW) -> Tuple[float, float]:
+def utilization(flops: float, mem_bytes: float, time_s: float,
+                peaks: Optional[DevicePeaks]
+                ) -> Tuple[Optional[float], Optional[float]]:
     """(MFU, MBU) for an interval: achieved FLOP/s and HBM byte/s as a
-    fraction of the chip peaks.  MFU = model FLOPs utilization (analytic
-    useful FLOPs / peak compute); MBU = memory-bandwidth utilization
-    (weight + KV traffic / peak HBM bandwidth).  (0, 0) on empty
-    intervals."""
+    fraction of the chip's bf16 and HBM peaks.  MFU = model FLOPs
+    utilization (analytic useful FLOPs / peak compute); MBU =
+    memory-bandwidth utilization (weight + KV traffic / peak HBM
+    bandwidth).  (None, None) when the device has no peaks ("not
+    measured"); (0, 0) on empty intervals."""
+    if peaks is None:
+        return None, None
     if time_s <= 0:
         return 0.0, 0.0
-    return flops / (time_s * peak_flops), mem_bytes / (time_s * hbm_bw)
+    return (flops / (time_s * peaks.bf16_flops),
+            mem_bytes / (time_s * peaks.hbm_bw))
 
 
 def model_flops(cfg, shape) -> float:

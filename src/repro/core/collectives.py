@@ -24,22 +24,10 @@ _STATE = threading.local()
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
-    """`jax.shard_map` across jax versions.  Newer releases expose it at the
-    top level with `check_vma=`; older ones at jax.experimental.shard_map
-    with `check_rep=`.  Replication checking is off either way (manual-SPMD
-    model code psums explicitly)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            pass                              # pre-check_vma signature
-    # check_rep=False: manual-SPMD code psums replication axes explicitly,
-    # which the old rep checker cannot always infer (multi-pod grad sync)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """`jax.shard_map` with replication checking off (manual-SPMD model
+    code psums replication axes explicitly)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def set_reduce_method(method: str) -> None:
@@ -60,15 +48,8 @@ def _norm(axes: Axes) -> Tuple[str, ...]:
 
 
 def one_axis_size(a) -> int:
-    """Static size of one named axis, across jax versions (`jax.lax.
-    axis_size` is newer; older releases expose it via `jax.core.axis_frame`,
-    which returns either the size or a frame carrying it)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(a)
-    import jax.core as jcore
-    fr = jcore.axis_frame(a)
-    return fr if isinstance(fr, int) else fr.size
+    """Static size of one named axis."""
+    return jax.lax.axis_size(a)
 
 
 def axis_size(axes: Axes) -> int:
@@ -79,10 +60,13 @@ def axis_size(axes: Axes) -> int:
 
 
 def axis_index(axes: Axes):
-    """Linearized index over possibly-multiple axes (C order: first major)."""
+    """Linearized index over possibly-multiple axes (C order: first major).
+    A static 0 when the axes span one device (no axes, or all of size 1),
+    so offsets built from it stay Python ints and static-offset kernels
+    (ops.flash_attention) keep their Pallas path."""
     axes = _norm(axes)
-    if not axes:
-        return jnp.zeros((), jnp.int32)
+    if axis_size(axes) == 1:
+        return 0
     idx = jnp.zeros((), jnp.int32)
     for a in axes:
         idx = idx * one_axis_size(a) + jax.lax.axis_index(a)

@@ -9,8 +9,12 @@ max/rescale/sum tree the paper distributes across clusters.  The identical
 merge rule combines *cross-chip* partials in core/distributed_softmax.py,
 so chip-local and pod-level softmax use one primitive.
 
-Grid: (B, KV, n_chunks) with the chunk dim innermost; partials are merged
-in-kernel through VMEM scratch (single pass over the cache).
+Grid: (B, n_chunks) with the chunk dim innermost; each step folds one
+chunk of every KV head and partials are merged in-kernel through VMEM
+scratch (single pass over the cache).  The caches are viewed as
+[..., KV*D] (a free reshape), which keeps every block's last two dims
+TPU-tileable — a per-head (1, chunk, 1, D) block is refused by the
+compiler.
 
 `paged_decode_attention` is the block-paged variant of the same primitive:
 the cache is a global pool of fixed-size KV blocks and each slot owns an
@@ -35,19 +39,23 @@ NEG_INF = -1e30
 
 
 def _online_merge(ci, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
-                  mask, live, sm_scale: float, k_scale=None, v_scale=None):
+                  mask, live, sm_scale: float, k_scales=None, v_scales=None):
     """Shared split-KV cell body: fold one masked KV chunk's scores into the
-    (m, l, acc) scratch with the online-softmax rescale rule, initializing
-    the scratch on the first chunk.  `mask`: [G, chunk] validity of this
-    chunk's positions; `live`: scalar — False when the whole chunk is
-    masked, skipping its dot work entirely (a fully-masked chunk is a
-    no-op: corr = 1, p = 0).  The caller writes the output on the last
-    chunk.
+    (m, l, acc) scratch with the online-softmax rescale rule, for every KV
+    head of the chunk, initializing the scratch on the first chunk.
+    `mask`: [G, chunk] validity of this chunk's positions (shared by all
+    heads); `live`: scalar — False when the whole chunk is masked, skipping
+    its dot work entirely (a fully-masked chunk is a no-op: corr = 1,
+    p = 0).  The caller writes the output on the last chunk.
 
-    `k_scale`/`v_scale`: per-(block, head) fp32 dequant scalars for int8
-    K/V chunks.  The scale is constant over this chunk's tokens and dims,
-    so it commutes past both dots: scores pick up `k_scale` after Q.K^T
-    and the P.V contribution picks up `v_scale` — exact dequantization
+    q_ref: [1, KV, G, D]; k/v_ref: [1, chunk, KV*D] — one KV chunk with all
+    heads side by side on the lane axis, so head h is the lane-aligned
+    column slice [h*D, (h+1)*D).  Scratch: m/l [KV, G, 1], acc [KV, G, D].
+
+    `k_scales`/`v_scales`: per-head fp32 dequant scalars (length KV) for
+    int8 K/V chunks.  A scale is constant over its chunk's tokens and dims,
+    so it commutes past both dots: scores pick up the K scale after Q.K^T
+    and the P.V contribution picks up the V scale — exact dequantization
     without ever materializing fp K/V tiles."""
     @pl.when(ci == 0)
     def _init():
@@ -57,43 +65,55 @@ def _online_merge(ci, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(live)
     def _fold():
-        q = q_ref[0, 0]                                 # [G, D]
-        k = k_ref[:, :, 0, :][0]                        # [chunk, D]
-        v = v_ref[:, :, 0, :][0]
-        if k_scale is not None:
-            # int8 chunk: run the dots in fp32 (int8 values are exact there)
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32)
+        kv, d = q_ref.shape[1], q_ref.shape[3]
+        for h in range(kv):
+            q = q_ref[0, h]                             # [G, D]
+            k = k_ref[0, :, h * d:(h + 1) * d]          # [chunk, D]
+            v = v_ref[0, :, h * d:(h + 1) * d]
+            if k_scales is not None:
+                # int8 chunk: run the dots in fp32 (int8 values are exact)
+                q = q.astype(jnp.float32)
+                k = k.astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * sm_scale
+            if k_scales is not None:
+                s = s * k_scales[h]
+            s = jnp.where(mask, s, NEG_INF)
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if k_scale is not None:
-            s = s * k_scale
-        s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[h]                           # [G, 1]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+            m_ref[h] = m_new
+            if v_scales is not None:
+                pv = jax.lax.dot_general(
+                    p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32) * v_scales[h]
+            else:
+                pv = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            acc_ref[h] = acc_ref[h] * corr + pv
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        m_ref[...] = m_new
-        if v_scale is not None:
-            pv = jax.lax.dot_general(
-                p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * v_scale
-        else:
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
+
+def _scratch(KV: int, G: int, D: int):
+    return [pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, 1), jnp.float32),
+            pltpu.VMEM((KV, G, D), jnp.float32)]
+
+
+def _normalized(acc_ref, l_ref):
+    return acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                    *, block_kv: int, window: int, sm_scale: float):
-    """q_ref: [1, 1, G, D]; k/v_ref: [1, block_kv, 1, D];
-    len_ref: scalar-prefetch [B] valid lengths; o_ref: [1, 1, G, D]."""
+    """q_ref: [1, KV, G, D]; k/v_ref: [1, block_kv, KV*D];
+    len_ref: scalar-prefetch [B] valid lengths; o_ref: [1, KV, G, D]."""
     b = pl.program_id(0)
-    ci = pl.program_id(2)
+    ci = pl.program_id(1)
     g = q_ref.shape[2]
     length = len_ref[b]
     pos = jax.lax.broadcasted_iota(jnp.int32, (g, block_kv), 1) + ci * block_kv
@@ -105,18 +125,18 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     _online_merge(ci, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
                   mask=mask, live=live, sm_scale=sm_scale)
 
-    @pl.when(ci == pl.num_programs(2) - 1)
+    @pl.when(ci == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = _normalized(acc_ref, l_ref).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_kv", "interpret"))
 def decode_attention(q, k_cache, v_cache, length, *, window=0, block_kv=512,
                      interpret=False):
     """q: [B, H, D]; caches: [B, S, KV, D]; length: [B] or scalar valid
-    lengths.  Returns [B, H, D]."""
+    lengths.  Returns [B, H, D].  Grid (B, chunks): each step folds one
+    chunk of every KV head (the cache rows are viewed as [B, S, KV*D], a
+    free reshape, so a chunk is one contiguous DMA)."""
     B, H, D = q.shape
     _, S, KV, _ = k_cache.shape
     G = H // KV
@@ -126,27 +146,25 @@ def decode_attention(q, k_cache, v_cache, length, *, window=0, block_kv=512,
     if pad:
         k_cache = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    nc = k_cache.shape[1] // block_kv
+    Sp = k_cache.shape[1]
+    nc = Sp // block_kv
     length = jnp.broadcast_to(jnp.asarray(length, jnp.int32), (B,))
     qr = q.reshape(B, KV, G, D)
+    kr = k_cache.reshape(B, Sp, KV * D)
+    vr = v_cache.reshape(B, Sp, KV * D)
 
+    kv_spec = pl.BlockSpec((1, block_kv, KV * D),
+                           lambda b, c, len_ref: (b, c, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, KV, nc),
+        grid=(B, nc),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, c, len_ref: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_kv, 1, D),
-                         lambda b, h, c, len_ref: (b, c, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, D),
-                         lambda b, h, c, len_ref: (b, c, h, 0)),
+            pl.BlockSpec((1, KV, G, D), lambda b, c, len_ref: (b, 0, 0, 0)),
+            kv_spec, kv_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, c, len_ref: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, KV, G, D),
+                               lambda b, c, len_ref: (b, 0, 0, 0)),
+        scratch_shapes=_scratch(KV, G, D),
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_kv=block_kv, window=window,
@@ -154,7 +172,7 @@ def decode_attention(q, k_cache, v_cache, length, *, window=0, block_kv=512,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         interpret=interpret,
-    )(length, qr, k_cache, v_cache)
+    )(length, qr, kr, vr)
     return out.reshape(B, H, D)
 
 
@@ -176,45 +194,54 @@ def _paged_mask(tab_ref, len_ref, b, e, g: int, block_size: int):
     return (pos < len_ref[b]) & (tab_ref[b, e] >= 0), live
 
 
-def _paged_scales(tab_ref, ks_ref, vs_ref, b, h, e):
-    """Dequant scalars for this grid step's (block, head) — one pool block
-    per step, so a single SMEM lookup each.  Absent entries (t < 0) read
-    block 0's scale; their fold is dead (`live` is False)."""
+def _paged_scales(tab_ref, ks_ref, vs_ref, b, e, kv: int):
+    """Per-head dequant scalars for this grid step's pool block — one SMEM
+    lookup per (block, head).  Absent entries (t < 0) read block 0's
+    scales; their fold is dead (`live` is False)."""
     if ks_ref is None:
         return None, None
     t = jnp.maximum(tab_ref[b, e], 0)
-    return ks_ref[t, h], vs_ref[t, h]
+    return ([ks_ref[t, h] for h in range(kv)],
+            [vs_ref[t, h] for h in range(kv)])
+
+
+def _paged_fold(refs, quantized: bool, n_out: int, block_size: int,
+                sm_scale: float):
+    """Unpack the paged kernels' refs and fold this step's pool block.
+    -> (e, outs, m_ref, l_ref, acc_ref)."""
+    if quantized:
+        tab_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref = refs[:7]
+        rest = refs[7:]
+    else:
+        tab_ref, len_ref, q_ref, k_ref, v_ref = refs[:5]
+        ks_ref = vs_ref = None
+        rest = refs[5:]
+    outs, (m_ref, l_ref, acc_ref) = rest[:n_out], rest[n_out:]
+    b = pl.program_id(0)
+    e = pl.program_id(1)
+    mask, live = _paged_mask(tab_ref, len_ref, b, e, q_ref.shape[2],
+                             block_size)
+    ks, vs = _paged_scales(tab_ref, ks_ref, vs_ref, b, e, q_ref.shape[1])
+    _online_merge(e, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                  mask=mask, live=live, sm_scale=sm_scale,
+                  k_scales=ks, v_scales=vs)
+    return e, outs, m_ref, l_ref, acc_ref
 
 
 def _paged_decode_kernel(*refs, block_size: int, sm_scale: float,
                          quantized: bool):
-    """q_ref: [1, 1, G, D]; k/v_ref: [1, block_size, 1, D] — the pool block
-    the slot's table names for entry `e` (the index map dereferenced it);
-    tab_ref: scalar-prefetch [B, MB] block tables (< 0 = absent);
-    len_ref: scalar-prefetch [B] valid lengths.  Quantized pools add
-    ks/vs_ref: scalar-prefetch [NB, KV] fp32 per-block-per-head scales."""
-    if quantized:
-        (tab_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref,
-         m_ref, l_ref, acc_ref) = refs
-    else:
-        (tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-         m_ref, l_ref, acc_ref) = refs
-        ks_ref = vs_ref = None
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    e = pl.program_id(2)
-    mask, live = _paged_mask(tab_ref, len_ref, b, e, q_ref.shape[2],
-                             block_size)
-    ks, vs = _paged_scales(tab_ref, ks_ref, vs_ref, b, h, e)
-    _online_merge(e, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-                  mask=mask, live=live, sm_scale=sm_scale,
-                  k_scale=ks, v_scale=vs)
+    """q_ref: [1, KV, G, D]; k/v_ref: [1, block_size, KV*D] — the pool
+    block the slot's table names for entry `e` (the index map dereferenced
+    it), all heads; tab_ref: scalar-prefetch [B, MB] block tables (< 0 =
+    absent); len_ref: scalar-prefetch [B] valid lengths.  Quantized pools
+    add ks/vs_ref: scalar-prefetch [NB, KV] fp32 per-block-per-head
+    scales."""
+    e, (o_ref,), m_ref, l_ref, acc_ref = _paged_fold(
+        refs, quantized, 1, block_size, sm_scale)
 
-    @pl.when(e == pl.num_programs(2) - 1)
+    @pl.when(e == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = _normalized(acc_ref, l_ref).astype(o_ref.dtype)
 
 
 def _paged_partials_kernel(*refs, block_size: int, sm_scale: float,
@@ -222,63 +249,45 @@ def _paged_partials_kernel(*refs, block_size: int, sm_scale: float,
     """As _paged_decode_kernel but emits the raw (o, m, l) online-softmax
     partials instead of normalizing — the cross-shard T4 merge
     (core/attention.merge_partials) combines per-device pool shards."""
-    if quantized:
-        (tab_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref,
-         mo_ref, lo_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        (tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-         mo_ref, lo_ref, m_ref, l_ref, acc_ref) = refs
-        ks_ref = vs_ref = None
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    e = pl.program_id(2)
-    mask, live = _paged_mask(tab_ref, len_ref, b, e, q_ref.shape[2],
-                             block_size)
-    ks, vs = _paged_scales(tab_ref, ks_ref, vs_ref, b, h, e)
-    _online_merge(e, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-                  mask=mask, live=live, sm_scale=sm_scale,
-                  k_scale=ks, v_scale=vs)
+    e, (o_ref, mo_ref, lo_ref), m_ref, l_ref, acc_ref = _paged_fold(
+        refs, quantized, 3, block_size, sm_scale)
 
-    @pl.when(e == pl.num_programs(2) - 1)
+    @pl.when(e == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[0, 0] = acc_ref[...]
-        mo_ref[0, 0] = m_ref[...]
-        lo_ref[0, 0] = l_ref[...]
+        o_ref[0] = acc_ref[...]
+        mo_ref[0] = m_ref[...]
+        lo_ref[0] = l_ref[...]
 
 
 def _paged_call(kernel, q, k_pool, v_pool, block_tables, lengths, out_shape,
                 out_specs, interpret, k_scale=None, v_scale=None):
-    """Shared pallas_call plumbing for the paged kernels: grid (slot,
-    kv_head, table entry) with scalar-prefetched tables dereferenced by the
-    k/v index maps — each step DMAs exactly one owned pool block.  Int8
-    pools additionally prefetch the [NB, KV] dequant scale tables."""
+    """Shared pallas_call plumbing for the paged kernels: grid (slot, table
+    entry) with scalar-prefetched tables dereferenced by the k/v index maps
+    — each step DMAs exactly one owned pool block, every KV head at once
+    (the pool is viewed as [NB, BS, KV*D], a free reshape).  Int8 pools
+    additionally prefetch the [NB, KV] dequant scale tables."""
     B, KV, G, D = q.shape
-    _, BS, _, _ = k_pool.shape
+    NB, BS, _, _ = k_pool.shape
     MB = block_tables.shape[1]
     sm_scale = float(1.0 / (D ** 0.5))
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     block_tables = block_tables.astype(jnp.int32)
     quantized = k_scale is not None
 
-    def kv_index(b, h, e, tab_ref, *_pref):
+    def kv_index(b, e, tab_ref, *_pref):
         t = tab_ref[b, e]
-        return (jnp.where(t < 0, 0, t), 0, h, 0)   # absent -> any block, masked
+        return (jnp.where(t < 0, 0, t), 0, 0)    # absent -> any block, masked
 
+    kv_spec = pl.BlockSpec((1, BS, KV * D), kv_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 if quantized else 2,
-        grid=(B, KV, MB),
+        grid=(B, MB),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D),
-                         lambda b, h, e, *_pref: (b, h, 0, 0)),
-            pl.BlockSpec((1, BS, 1, D), kv_index),
-            pl.BlockSpec((1, BS, 1, D), kv_index),
+            pl.BlockSpec((1, KV, G, D), lambda b, e, *_pref: (b, 0, 0, 0)),
+            kv_spec, kv_spec,
         ],
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
+        scratch_shapes=_scratch(KV, G, D),
     )
     prefetch = (block_tables, lengths)
     if quantized:
@@ -290,7 +299,12 @@ def _paged_call(kernel, q, k_pool, v_pool, block_tables, lengths, out_shape,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(*prefetch, q, k_pool, v_pool)
+    )(*prefetch, q, k_pool.reshape(NB, BS, KV * D),
+      v_pool.reshape(NB, BS, KV * D))
+
+
+def _slot_spec(*block):
+    return pl.BlockSpec((1, *block), lambda b, e, *_pref: (b, 0, 0, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -311,8 +325,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
         _paged_decode_kernel, q.reshape(B, KV, G, D), k_pool, v_pool,
         block_tables, lengths,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, e, *_pref: (b, h, 0, 0)),
+        out_specs=_slot_spec(KV, G, D),
         interpret=interpret, k_scale=k_scale, v_scale=v_scale)
     return out.reshape(B, H, D)
 
@@ -327,16 +340,13 @@ def paged_decode_partials(q, k_pool, v_pool, block_tables, lengths, *,
     B, H, D = q.shape
     KV = k_pool.shape[2]
     G = H // KV
-    hw = pl.BlockSpec((1, 1, G),
-                      lambda b, h, e, *_pref: (b, h, 0))
+    stat = jax.ShapeDtypeStruct((B, KV, G, 1), jnp.float32)
     o, m, l = _paged_call(
         _paged_partials_kernel, q.reshape(B, KV, G, D), k_pool, v_pool,
         block_tables, lengths,
         out_shape=[jax.ShapeDtypeStruct((B, KV, G, D), jnp.float32),
-                   jax.ShapeDtypeStruct((B, KV, G), jnp.float32),
-                   jax.ShapeDtypeStruct((B, KV, G), jnp.float32)],
-        out_specs=[pl.BlockSpec((1, 1, G, D),
-                                lambda b, h, e, *_pref: (b, h, 0, 0)),
-                   hw, hw],
+                   stat, stat],
+        out_specs=[_slot_spec(KV, G, D), _slot_spec(KV, G, 1),
+                   _slot_spec(KV, G, 1)],
         interpret=interpret, k_scale=k_scale, v_scale=v_scale)
     return o.reshape(B, H, D), m.reshape(B, H), l.reshape(B, H)

@@ -6,7 +6,9 @@ Every model-facing op goes through this module.  Dispatch modes:
               implementations compute the *same math in the same precision*
               (fp32 accumulation / fp32 softmax), so CPU dry-run lowering
               produces representative FLOP/byte counts while TPU execution
-              hits the hand-tiled kernels.
+              hits the hand-tiled kernels.  On TPU every auto dispatch is
+              counted by op name (`dispatch_record()`), so a dispatch that
+              took the reference there is visible (`hidden_fallbacks()`).
   ``pallas``            force compiled Pallas (TPU only).
   ``interpret``         force Pallas interpret mode (CPU correctness runs).
   ``ref``               force the jnp oracle.
@@ -68,7 +70,8 @@ __all__ = [
     "paged_chunk_partials", "split_quantized", "matmul", "matmul_swiglu",
     "fused_matmul",
     "fused_matmul_swiglu", "expert_swiglu", "residual_norm", "rmsnorm",
-    "layernorm", "norm", "ssd", "ssd_decode",
+    "layernorm", "norm", "ssd", "ssd_decode", "dispatch_record",
+    "reset_dispatch_record", "hidden_fallbacks", "KERNEL_OPS", "REF_ONLY",
 ]
 
 _STATE = threading.local()
@@ -103,6 +106,10 @@ def kernel_mode(mode: str):
         _STATE.mode = prev
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def _use_pallas() -> tuple[bool, bool]:
     """-> (use_pallas, interpret)"""
     mode = get_mode()
@@ -112,8 +119,46 @@ def _use_pallas() -> tuple[bool, bool]:
         return True, False
     if mode == "interpret":
         return True, True
-    on_tpu = jax.default_backend() == "tpu"
-    return (True, False) if on_tpu else (False, False)
+    return (True, False) if _on_tpu() else (False, False)
+
+
+# ops with a Pallas kernel, and those without one (the reference IS their
+# TPU path)
+KERNEL_OPS = ("flash_attention", "decode_attention", "paged_decode_attention",
+              "paged_decode_partials", "matmul", "matmul_swiglu",
+              "fused_matmul", "fused_matmul_swiglu", "expert_swiglu",
+              "residual_norm", "rmsnorm", "layernorm", "ssd")
+REF_ONLY = ("paged_chunk_partials", "ssd_decode")
+_RECORD: dict = {}            # op -> {"pallas": n, "ref": n}
+_RECORD_LOCK = threading.Lock()
+
+
+def _note(op: str, pallas: bool) -> bool:
+    """Count one trace-time dispatch of `op` in auto mode on a TPU backend
+    (explicitly forced modes are never counted); returns `pallas`."""
+    if get_mode() == "auto" and _on_tpu():
+        with _RECORD_LOCK:
+            row = _RECORD.setdefault(op, {"pallas": 0, "ref": 0})
+            row["pallas" if pallas else "ref"] += 1
+    return pallas
+
+
+def dispatch_record() -> dict:
+    """{op: {"pallas": n, "ref": n}} of the auto-mode dispatches traced on
+    TPU since the last `reset_dispatch_record()`."""
+    with _RECORD_LOCK:
+        return {op: dict(row) for op, row in _RECORD.items()}
+
+
+def reset_dispatch_record() -> None:
+    with _RECORD_LOCK:
+        _RECORD.clear()
+
+
+def hidden_fallbacks() -> list:
+    """Ops with a Pallas kernel that took the reference on TPU."""
+    return sorted(op for op, row in dispatch_record().items()
+                  if row["ref"] and op in KERNEL_OPS)
 
 
 # --------------------------------------------------------------------------
@@ -130,7 +175,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
     materialization), keeping dry-run FLOP/byte counts representative.
     """
     use, interp = _use_pallas()
-    if use and isinstance(q_offset, int):
+    if _note("flash_attention", use and isinstance(q_offset, int)):
         return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                    q_offset=q_offset, block_q=block_q,
                                    block_kv=block_kv, interpret=interp)
@@ -145,7 +190,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
 def decode_attention(q, k_cache, v_cache, length, *, window=0, block_kv=512):
     """q: [B, H, D]; caches: [B, S, KV, D]; length: [B] valid entries."""
     use, interp = _use_pallas()
-    if use:
+    if _note("decode_attention", use):
         return _fd.decode_attention(q, k_cache, v_cache, length,
                                     window=window, block_kv=block_kv,
                                     interpret=interp)
@@ -160,7 +205,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     lengths: [B] valid tokens per slot.  Fully normalized output.
     `k_scale`/`v_scale` ([NB, KV] fp32): dequant scales for int8 pools."""
     use, interp = _use_pallas()
-    if use:
+    if _note("paged_decode_attention", use):
         return _fd.paged_decode_attention(q, k_pool, v_pool, block_tables,
                                           lengths, k_scale=k_scale,
                                           v_scale=v_scale, interpret=interp)
@@ -176,7 +221,7 @@ def paged_decode_partials(q, k_pool, v_pool, block_tables, lengths, *,
     (core/attention.merge_partials); same operands as
     `paged_decode_attention`, run per cache shard on its local pool."""
     use, interp = _use_pallas()
-    if use:
+    if _note("paged_decode_partials", use):
         return _fd.paged_decode_partials(q, k_pool, v_pool, block_tables,
                                          lengths, k_scale=k_scale,
                                          v_scale=v_scale, interpret=interp)
@@ -200,6 +245,7 @@ def paged_chunk_partials(q, k_pool, v_pool, block_tables, q_pos, lengths, *,
     intermediates are bounded by C x S — the reference keeps math and
     precision identical to the paged decode oracle (vmemk scope: the
     intermediates live in VMEM once a Pallas chunk kernel lands)."""
+    _note("paged_chunk_partials", False)
     with jax.named_scope("vmemk_chunk"):
         return _ref.paged_chunk_partials_ref(q, k_pool, v_pool, block_tables,
                                              q_pos, lengths, k_scale=k_scale,
@@ -224,7 +270,7 @@ def matmul(a, b, *, activation="none", out_dtype=None,
            block_m=128, block_n=128, block_k=512):
     b, b_scale = split_quantized(b)
     use, interp = _use_pallas()
-    if use and a.ndim == 2:
+    if _note("matmul", use and a.ndim == 2):
         return _mm.matmul(a, b, activation=activation, b_scale=b_scale,
                           out_dtype=out_dtype, block_m=block_m,
                           block_n=block_n, block_k=block_k,
@@ -244,7 +290,7 @@ def matmul_swiglu(a, b_gate, b_up, *, out_dtype=None,
     b_gate, g_scale = split_quantized(b_gate)
     b_up, u_scale = split_quantized(b_up)
     use, interp = _use_pallas()
-    if use and a.ndim == 2:
+    if _note("matmul_swiglu", use and a.ndim == 2):
         return _mm.matmul_swiglu(a, b_gate, b_up, bg_scale=g_scale,
                                  bu_scale=u_scale, out_dtype=out_dtype,
                                  block_m=block_m, block_n=block_n,
@@ -288,7 +334,7 @@ def fused_matmul(x, w, *, prologue=None, epilogue=None, compute_dtype=None,
     ep = epilogue or Epilogue()
     out_dtype = ep.out_dtype or dot_dtype or x.dtype
     use, interp = _use_pallas()
-    if use:
+    if _note("fused_matmul", use):
         lead = x.shape[:-1]
         K = x.shape[-1]
         N = w.shape[-1]
@@ -321,7 +367,7 @@ def fused_matmul_swiglu(x, wg, wu, *, prologue=None, residual=None,
     wg, g_scale = split_quantized(wg)
     wu, u_scale = split_quantized(wu)
     use, interp = _use_pallas()
-    if use:
+    if _note("fused_matmul_swiglu", use):
         lead = x.shape[:-1]
         K = x.shape[-1]
         N = wg.shape[-1]
@@ -353,7 +399,7 @@ def expert_swiglu(xe, wg, wu, *, compute_dtype=None, out_dtype=None):
     Pallas path vmaps the fused swiglu kernel over the expert dim."""
     out_dtype = out_dtype or xe.dtype
     use, interp = _use_pallas()
-    if use:
+    if _note("expert_swiglu", use):
         cd = compute_dtype or xe.dtype
         import functools
         f = functools.partial(_mm.matmul_swiglu, out_dtype=out_dtype,
@@ -376,7 +422,7 @@ def residual_norm(x, y, params, kind: str):
     the sub-layer boundary a GEMM epilogue can't absorb (the sum is both
     the next residual and the norm input).  -> (h, r)."""
     use, interp = _use_pallas()
-    if use:
+    if _note("residual_norm", use):
         if kind == "rmsnorm":
             return _norm.residual_rmsnorm(x, y, params["scale"],
                                           interpret=interp)
@@ -397,14 +443,14 @@ def residual_norm(x, y, params, kind: str):
 
 def rmsnorm(x, gamma, *, eps=RMS_EPS):
     use, interp = _use_pallas()
-    if use:
+    if _note("rmsnorm", use):
         return _norm.rmsnorm(x, gamma, eps=eps, interpret=interp)
     return _ref.rmsnorm_ref(x, gamma, eps=eps)
 
 
 def layernorm(x, gamma, beta, *, eps=LN_EPS):
     use, interp = _use_pallas()
-    if use:
+    if _note("layernorm", use):
         return _norm.layernorm(x, gamma, beta, eps=eps, interpret=interp)
     return _ref.layernorm_ref(x, gamma, beta, eps=eps)
 
@@ -428,7 +474,7 @@ def ssd(x, dt, A, B, C, D, *, chunk=128):
     falls back to the per-head grid when the [H,P,N] state would overflow
     VMEM."""
     use, interp = _use_pallas()
-    if use and x.shape[1] % min(chunk, x.shape[1]) == 0:
+    if _note("ssd", use and x.shape[1] % min(chunk, x.shape[1]) == 0):
         H, P = x.shape[2], x.shape[3]
         N = B.shape[-1]
         c = min(chunk, x.shape[1])
@@ -451,4 +497,5 @@ def _best_chunk(S: int, chunk: int) -> int:
 
 def ssd_decode(x, dt, A, B, C, D, h):
     """Single-step SSD state update (no kernel needed — pure VPU math)."""
+    _note("ssd_decode", False)
     return _ref.ssd_decode_ref(x, dt, A, B, C, D, h)

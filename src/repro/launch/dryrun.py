@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture x input shape) cell this lowers + compiles the real
@@ -18,7 +15,15 @@ Usage:
 
 --all spawns one subprocess per cell (fresh XLA, bounded memory, isolated
 failures) and writes one JSON per cell plus a summary table.
+
+This is a CPU projection over 512 fake host devices: the module pins
+`JAX_PLATFORMS=cpu` as it is imported, so neither it nor its children ever
+load the TPU library (on a chip machine they would hold the chip).
 """
+import os
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
 import argparse
 import json
 import subprocess

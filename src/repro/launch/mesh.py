@@ -10,14 +10,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """`jax.make_mesh` across jax versions: `axis_types` (and
-    `jax.sharding.AxisType`) only exist on newer releases; every axis here
-    is Auto either way, which is also the old default."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

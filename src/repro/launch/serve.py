@@ -79,6 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_for
 from repro.models import lm
 from repro.serving import (EncodeTask, InferenceEngine, Request,
@@ -117,6 +118,7 @@ def build_trace(cfg, args) -> list:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -263,8 +265,10 @@ def main(argv=None) -> int:
                   f"{stats.spec_branch_utilization:.0%}")
     util = stats.phase_util()
     if util:
-        print("  util: " + " | ".join(
-            f"{ph} MFU {row['mfu']:.2%} MBU {row['mbu']:.2%} "
+        def pct(x):
+            return "not measured" if x is None else f"{x:.2%}"
+        print(f"  util on {stats.device_kind}: " + " | ".join(
+            f"{ph} MFU {pct(row['mfu'])} MBU {pct(row['mbu'])} "
             f"({row['time_s'] * 1e3:.0f}ms)"
             for ph, row in util.items()))
     if tracer is not None:

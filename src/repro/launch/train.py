@@ -17,11 +17,12 @@ import sys
 import time
 
 # compute/comm overlap: latency-hiding scheduler (effective on TPU; harmless
-# on CPU).  Must be set before jax initializes.
-os.environ.setdefault(
-    "LIBTPU_INIT_ARGS",
-    "--xla_tpu_enable_latency_hiding_scheduler=true "
-    "--xla_tpu_enable_async_collective_fusion=true")
+# on CPU).  Must be set before jax initializes.  Appended to whatever the
+# machine already passes to libtpu, never replacing it.
+os.environ["LIBTPU_INIT_ARGS"] = " ".join(filter(None, (
+    os.environ.get("LIBTPU_INIT_ARGS", ""),
+    "--xla_tpu_enable_latency_hiding_scheduler=true",
+    "--xla_tpu_enable_async_collective_fusion=true")))
 
 import jax
 import jax.numpy as jnp

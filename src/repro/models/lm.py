@@ -29,7 +29,8 @@ from repro.core import collectives as col
 from repro.core.embedding import (ce_loss, embed_sequence, embed_token,
                                   embedding_param_dims,
                                   embedding_param_shapes, greedy_token,
-                                  init_embedding, sample_token, sample_topn)
+                                  init_embedding, logits_local, sample_token,
+                                  sample_topn)
 from repro.core.nn import act_dtype
 from repro.core.rope import sinusoidal_positions
 from repro.kernels import ops
@@ -299,6 +300,37 @@ def forward_prefill(params, batch, *, plan: Plan, cfg, policy, max_seq: int,
     sans "step"); greedy decoding when None.
     `compact_kv`: emit full-context KV caches at prompt length instead of
     padded to `max_seq` (paged admission scatters them into pool blocks)."""
+    x_last, head_norm, caches, pos = _prefill_last(
+        params, batch, plan=plan, cfg=cfg, policy=policy, max_seq=max_seq,
+        prompt_len=prompt_len, compact_kv=compact_kv)
+    if lane is None:
+        tok = greedy_token(x_last, params["embedding"]["unemb"], plan=plan,
+                           cfg=cfg, policy=policy, norm=head_norm)
+    else:
+        tok = sample_token(x_last, params["embedding"]["unemb"],
+                           dict(lane, step=pos), plan=plan, cfg=cfg,
+                           policy=policy, norm=head_norm)
+    return tok, caches, pos
+
+
+def prefill_logits(params, batch, *, plan: Plan, cfg, policy):
+    """fp32 logits [B, Vp/tp] at each row's last prompt position (padded
+    vocab columns masked): the scores `forward_prefill`'s greedy pick
+    takes the argmax of, for checks against a reference path."""
+    x_last, head_norm, _, _ = _prefill_last(
+        params, batch, plan=plan, cfg=cfg, policy=policy,
+        max_seq=total_seq(cfg, batch["tokens"].shape[1]), prompt_len=None,
+        compact_kv=True)
+    z, _ = logits_local(x_last, params["embedding"]["unemb"], plan=plan,
+                        cfg=cfg, policy=policy, norm=head_norm)
+    return z
+
+
+def _prefill_last(params, batch, *, plan: Plan, cfg, policy, max_seq: int,
+                  prompt_len, compact_kv: bool):
+    """The prompt pass up to the head: -> (raw residual at each row's last
+    prompt position [B, E], the final norm when it fuses into the head
+    GEMM (else already applied), caches, pos [B])."""
     x, _, _ = _embed_sequence(params, batch, plan=plan, cfg=cfg,
                               policy=policy, with_labels=False)
     memory = None
@@ -323,15 +355,7 @@ def forward_prefill(params, batch, *, plan: Plan, cfg, policy, max_seq: int,
     # fused head: select the raw residual row first (norm is row-wise, so
     # select-then-norm == norm-then-select) and fold the final norm into
     # the logits GEMM — the full-sequence normalized copy never exists
-    x_last = _residual_at(x, pos - 1, plan)
-    if lane is None:
-        tok = greedy_token(x_last, params["embedding"]["unemb"], plan=plan,
-                           cfg=cfg, policy=policy, norm=head_norm)
-    else:
-        tok = sample_token(x_last, params["embedding"]["unemb"],
-                           dict(lane, step=pos), plan=plan, cfg=cfg,
-                           policy=policy, norm=head_norm)
-    return tok, caches, pos
+    return _residual_at(x, pos - 1, plan), head_norm, caches, pos
 
 
 def forward_encode(params, batch, *, plan: Plan, cfg, policy,

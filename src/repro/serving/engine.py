@@ -223,16 +223,11 @@ class InferenceEngine:
         st.kv_dtype = self.runner.kv_dtype
         st.weight_bytes_per_device = self.runner.weight_bytes_per_device()
         st.kv_pool_bytes = self.runner.kv_pool_bytes()
-        # per-token FLOP / byte constants for phase_util()'s MFU / MBU
-        # attribution (analysis/roofline.py); encoder-only topologies and
-        # configs without an active-param count leave them 0 (phase_util
-        # then reports {})
+        # per-token FLOP / byte constants and the device kind for
+        # phase_util()'s MFU / MBU attribution (analysis/roofline.py)
         from repro.analysis.roofline import decoder_flops_per_token
-        try:
-            st.model_flops_per_token = decoder_flops_per_token(
-                self.runner.cfg)
-        except Exception:
-            st.model_flops_per_token = 0.0
+        st.model_flops_per_token = decoder_flops_per_token(self.runner.cfg)
+        st.device_kind = self.runner.device_kind
         if self.runner.paged:
             denom = (self.runner.layout.num_blocks
                      * self.runner.layout.block_size)

@@ -165,6 +165,13 @@ class ModelRunner:
             kv_cache_dtype=self.kv_dtype, weight_dtype=weight_dtype,
             fuse_epilogues=fuse_epilogues)
         self.layout = self.decode_step.aux["paged"]
+        if mesh is not None:
+            # lay the weights out once as every step's specs expect them
+            # (a no-op for params built with these shardings); unplaced
+            # params would be re-sent to the mesh on every step call
+            params = self.params = jax.device_put(
+                params, steps_mod.to_shardings(
+                    self.decode_step.aux["param_specs"], mesh))
         self._prefill_steps: Dict[tuple, steps_mod.StepBundle] = {}
         self._encode_steps: Dict[tuple, steps_mod.StepBundle] = {}
         self._chunk_steps: Dict[int, steps_mod.StepBundle] = {}
@@ -323,6 +330,13 @@ class ModelRunner:
         pools plus their scale leaves and any dense (ring / cross-attn /
         SSM) state."""
         return sum(_device_nbytes(x) for x in jax.tree.leaves(self.caches))
+
+    @property
+    def device_kind(self) -> str:
+        """`device_kind` of the devices the steps run on."""
+        devs = (self.mesh.devices.flat if self.mesh is not None
+                else jax.devices())
+        return next(iter(devs)).device_kind
 
     # -- capacity / bucket geometry ------------------------------------
     @property
@@ -560,8 +574,12 @@ class ModelRunner:
                                      blk[:n_full // bs])
 
     def _tables(self):
+        # every host -> device upload here is a copy (jnp.array): on the
+        # CPU backend jnp.asarray may alias the numpy buffer, and the host
+        # mirrors (tables, pos, tokens, lanes) are mutated in place while a
+        # dispatched step may not have read them yet
         if self._tables_dev is None:
-            self._tables_dev = jnp.asarray(self.block_tables)
+            self._tables_dev = jnp.array(self.block_tables)
         return self._tables_dev
 
     def ensure_decode_blocks(
@@ -660,7 +678,7 @@ class ModelRunner:
         padded = np.zeros((n, bucket), np.int32)
         for j, seq in enumerate(fulls):
             padded[j, :len(seq)] = seq
-        batch = {"tokens": jnp.asarray(padded)}
+        batch = {"tokens": jnp.array(padded)}
         if self.cfg.n_patches:
             batch["patches"] = jnp.zeros(
                 (n, self.cfg.n_patches, self.cfg.d_model), jnp.bfloat16)
@@ -680,8 +698,8 @@ class ModelRunner:
         else:
             tables = np.zeros((n, 1), np.int32)      # unused by the scatter
         self.caches = self._scatter(self.caches, caches_g,
-                                    jnp.asarray(slots, jnp.int32),
-                                    jnp.asarray(tables))
+                                    jnp.array(slots, jnp.int32),
+                                    jnp.array(tables))
         tok_np = np.asarray(tok)
         self.tokens[slots] = tok_np
         self._tok_dev = None        # host token write: the chained device
@@ -769,9 +787,9 @@ class ModelRunner:
         for j, seq in enumerate(fulls):
             padded[j, :len(seq)] = seq
         _, dcaches, _ = step.fn(self.draft_params,
-                                {"tokens": jnp.asarray(padded)})
+                                {"tokens": jnp.array(padded)})
         self.draft_caches = self._draft_scatter(
-            self.draft_caches, dcaches, jnp.asarray(slots, jnp.int32),
+            self.draft_caches, dcaches, jnp.array(slots, jnp.int32),
             jnp.zeros((n, 1), jnp.int32))
         jax.block_until_ready(self.draft_caches)   # honest overhead timing
         for j, b in enumerate(slots):
@@ -808,10 +826,10 @@ class ModelRunner:
         row_table[0, :len(self._slot_blocks[b])] = self._slot_blocks[b]
         lane = stack_lanes([task.sampling])
         tok, self.caches, pos_end = step.fn(
-            self.params, jnp.asarray(chunk),
-            jnp.asarray([start], jnp.int32),
-            jnp.asarray([take], jnp.int32),
-            self.caches, jnp.asarray(row_table), lane)
+            self.params, jnp.array(chunk),
+            jnp.array([start], jnp.int32),
+            jnp.array([take], jnp.int32),
+            self.caches, jnp.array(row_table), lane)
         tok_np = int(np.asarray(tok)[0])          # blocks: honest timing
         pos_np = int(np.asarray(pos_end)[0])
         now = time.perf_counter()
@@ -870,8 +888,8 @@ class ModelRunner:
         instead of re-uploading the host mirror."""
         t0 = time.perf_counter()
         tok_in = (self._tok_dev if self._tok_dev is not None
-                  else jnp.asarray(self.tokens))
-        pos_d = jnp.asarray(self.pos)
+                  else jnp.array(self.tokens))
+        pos_d = jnp.array(self.pos)
         lane_d = device_lane(self.lane)
         if self.paged:
             tok_d, _, self.caches = self.decode_step.fn(
@@ -1091,8 +1109,8 @@ class ModelRunner:
                 feed[b] = (known[b][s] if s < len(known[b])
                            else int(last_out[b]))
             out_d, _, self.draft_caches = self.draft_decode_step.fn(
-                self.draft_params, jnp.asarray(feed),
-                jnp.asarray(starts + s, jnp.int32), self.draft_caches,
+                self.draft_params, jnp.array(feed),
+                jnp.array(starts + s, jnp.int32), self.draft_caches,
                 lane_d)
             last_out = np.asarray(out_d)
             for b in active:
@@ -1118,8 +1136,8 @@ class ModelRunner:
             chunk_len[b] = 1 + len(props)
         t1 = time.perf_counter()
         choices_d, self.caches, _ = self.verify_step.fn(
-            self.params, jnp.asarray(chunk), jnp.asarray(pos0, jnp.int32),
-            jnp.asarray(chunk_len), self.caches, self._tables(), lane_d)
+            self.params, jnp.array(chunk), jnp.array(pos0, jnp.int32),
+            jnp.array(chunk_len), self.caches, self._tables(), lane_d)
         choices = np.asarray(choices_d)           # blocks: honest timing
         dt = time.perf_counter() - t1
         self.steps_run += 1
@@ -1244,8 +1262,8 @@ class ModelRunner:
                 feed[b] = (known[b][s] if s < len(known[b])
                            else int(last_out[b]))
             out_d, alts_d, _, self.draft_caches = self.draft_topk_step.fn(
-                self.draft_params, jnp.asarray(feed),
-                jnp.asarray(starts + s, jnp.int32), self.draft_caches,
+                self.draft_params, jnp.array(feed),
+                jnp.array(starts + s, jnp.int32), self.draft_caches,
                 lane_d)
             last_out = np.asarray(out_d)
             alts = np.asarray(alts_d)
@@ -1280,9 +1298,9 @@ class ModelRunner:
             chunk_len[b] = n
         t1 = time.perf_counter()
         choices_d, self.caches, _ = self.tree_verify_step.fn(
-            self.params, jnp.asarray(chunk), jnp.asarray(pos0, jnp.int32),
-            jnp.asarray(chunk_len), jnp.asarray(depth_op),
-            jnp.asarray(anc_op), self.caches, self._tables(), lane_d)
+            self.params, jnp.array(chunk), jnp.array(pos0, jnp.int32),
+            jnp.array(chunk_len), jnp.array(depth_op),
+            jnp.array(anc_op), self.caches, self._tables(), lane_d)
         choices = np.asarray(choices_d)           # blocks: honest timing
         dt = time.perf_counter() - t1
         self.steps_run += 1
@@ -1399,14 +1417,14 @@ class ModelRunner:
         padded = np.zeros((n, bucket), np.int32)
         for j, task in enumerate(group):
             padded[j, :task.prompt_len] = np.asarray(task.prompt, np.int32)
-        batch = {"tokens": jnp.asarray(padded)}
+        batch = {"tokens": jnp.array(padded)}
         if self.cfg.n_patches:
             batch["patches"] = jnp.zeros(
                 (n, self.cfg.n_patches, self.cfg.d_model), jnp.bfloat16)
         if self.cfg.enc_schedule:
             batch["frames"] = jnp.zeros(
                 (n, self.cfg.enc_seq_padded, self.cfg.d_model), jnp.bfloat16)
-        pooled = step.fn(self.params, batch, jnp.asarray(lens, jnp.int32))
+        pooled = step.fn(self.params, batch, jnp.array(lens, jnp.int32))
         pooled_np = np.asarray(pooled)            # blocks: honest timing
         now = time.perf_counter()
         dt = now - t0

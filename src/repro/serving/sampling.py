@@ -89,8 +89,10 @@ def set_lane(lane: dict, slot: int, params: SamplingParams) -> dict:
 
 
 def device_lane(lane: dict) -> dict:
-    """Host lane -> device arrays for a jitted step call."""
-    return {k: jnp.asarray(v) for k, v in lane.items()}
+    """Host lane -> device arrays for a jitted step call.  Copies: on the
+    CPU backend `jnp.asarray` may alias the numpy buffer, which the host
+    mutates while a dispatched step may not have read it yet."""
+    return {k: jnp.array(v) for k, v in lane.items()}
 
 
 def stack_lanes(params_list) -> dict:

@@ -167,6 +167,7 @@ class EngineStats:
     # -- utilization attribution (serving/trace.py, analysis/roofline.py) ----
     # per-token constants the engine stamps at construction so phase_util()
     # can turn phase (time, token) sums into achieved MFU / MBU
+    device_kind: str = ""               # jax device_kind the engine runs on
     model_flops_per_token: float = 0.0  # analytic fwd FLOPs per position
     kv_bytes_per_token: float = 0.0     # KV bytes read per attended position
     # -- async overlapped host loop (engine overlap=True) --------------------
@@ -416,7 +417,7 @@ class EngineStats:
         return (self.block_slot_steps * self.kv_block_size
                 / self.token_slot_steps)
 
-    def phase_util(self) -> Dict[str, Dict[str, float]]:
+    def phase_util(self, peaks=None) -> Dict[str, Dict[str, float]]:
         """Per-phase achieved MFU / MBU from the engine's own counters.
 
         Joins each phase's (busy time, token positions, weight passes, KV
@@ -428,16 +429,23 @@ class EngineStats:
         recompute passes), then "verify" under speculation or "decode"
         otherwise (an engine runs one AR mode per session), plus "encode"
         for encoder-only traffic.  {} when the FLOP constant is unknown
-        (encoder-only config or externally built stats)."""
+        (stats not built by an engine).
+
+        `peaks` (analysis/roofline.DevicePeaks) defaults to the peak-table
+        entry of the engine's `device_kind`; on a device the table does
+        not list, "mfu"/"mbu" are None ("not measured") and the counts
+        stay."""
         if not self.model_flops_per_token:
             return {}
-        from repro.analysis.roofline import utilization
+        from repro.analysis.roofline import PEAKS, utilization
+        if peaks is None:
+            peaks = PEAKS.get(self.device_kind)
         wbytes = float(self.weight_bytes_per_device)
 
         def row(time_s, tokens, passes, kv_positions):
             flops = self.model_flops_per_token * tokens
             hbm = wbytes * passes + self.kv_bytes_per_token * kv_positions
-            mfu, mbu = utilization(flops, hbm, time_s)
+            mfu, mbu = utilization(flops, hbm, time_s, peaks)
             return {"time_s": time_s, "tokens": float(tokens),
                     "passes": float(passes),
                     "kv_positions": float(kv_positions),
@@ -468,6 +476,7 @@ class EngineStats:
         """JSON-ready snapshot (benchmarks/serving_bench.py)."""
         return {
             "batch_size": self.batch_size,
+            "device_kind": self.device_kind,
             "requests_submitted": self.requests_submitted,
             "requests_completed": self.requests_completed,
             "nar_tokens": self.nar_tokens,

@@ -206,8 +206,7 @@ def validate_chrome_trace(doc: dict) -> List[str]:
 # -- interval metrics: join step spans with the roofline FLOP/byte model -
 def derive_phase_metrics(events: Iterable[dict], *, flops_per_token: float,
                          weight_bytes: float, kv_bytes_per_token: float,
-                         peak_flops: Optional[float] = None,
-                         hbm_bw: Optional[float] = None) -> Dict[str, dict]:
+                         peaks=None) -> Dict[str, dict]:
     """Per-phase achieved MFU/MBU from recorded step spans.
 
     Every compute span (prefill / prefill_chunk / decode_step /
@@ -221,12 +220,12 @@ def derive_phase_metrics(events: Iterable[dict], *, flops_per_token: float,
             / (busy_s * bw)
 
     `flops_per_token` is the analytic decoder forward cost
-    (analysis/roofline.decoder_flops_per_token); peak/bw default to the
-    TPU v5e roofline constants.  The "draft" phase reports time only
-    (its FLOPs belong to a different, smaller model)."""
-    from repro.analysis import roofline
-    peak = peak_flops if peak_flops is not None else roofline.PEAK_BF16
-    bw = hbm_bw if hbm_bw is not None else roofline.HBM_BW
+    (analysis/roofline.decoder_flops_per_token); `peaks` is the recording
+    device's analysis/roofline.DevicePeaks — None (a device the peak
+    table does not list) reports MFU/MBU as None, "not measured", and
+    keeps the counts.  The "draft" phase reports time only (its FLOPs
+    belong to a different, smaller model)."""
+    from repro.analysis.roofline import utilization
     acc: Dict[str, dict] = {}
     for e in events:
         args = e.get("args") or {}
@@ -243,17 +242,12 @@ def derive_phase_metrics(events: Iterable[dict], *, flops_per_token: float,
         a["passes"] += int(args.get("passes", 0))
     out: Dict[str, dict] = {}
     for phase, a in acc.items():
-        t = a["time_s"]
         flops = flops_per_token * a["tokens"]
         mem = weight_bytes * a["passes"] + kv_bytes_per_token * a[
             "kv_positions"]
-        out[phase] = {
-            **a,
-            "flops": flops,
-            "hbm_bytes": mem,
-            "mfu": flops / (t * peak) if t > 0 else 0.0,
-            "mbu": mem / (t * bw) if t > 0 else 0.0,
-        }
+        mfu, mbu = utilization(flops, mem, a["time_s"], peaks)
+        out[phase] = {**a, "flops": flops, "hbm_bytes": mem,
+                      "mfu": mfu, "mbu": mbu}
     return out
 
 

@@ -241,3 +241,71 @@ def test_ssd_decode_step_matches_scan():
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(h_t), np.asarray(h_full),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_dispatch_record_names_tpu_fallbacks(monkeypatch):
+    """On a TPU backend every auto-mode dispatch is counted by op name: a
+    reference taken by an op that has a kernel is a hidden fallback, the
+    reference-only ops are not; forced modes and other backends are never
+    counted.  (eval_shape traces the dispatch without compiling.)"""
+    from repro.kernels import ops
+    def matmul(a, b):        # a fresh function per call: no trace cache
+        return jax.eval_shape(lambda a, b: ops.matmul(a, b), a, b)
+
+    a2 = jax.ShapeDtypeStruct((8, 128), jnp.float32)
+    a3 = jax.ShapeDtypeStruct((2, 8, 128), jnp.float32)
+    b = jax.ShapeDtypeStruct((128, 128), jnp.float32)
+    ops.reset_dispatch_record()
+    matmul(a3, b)                  # CPU: not counted
+    assert ops.dispatch_record() == {}
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    with ops.kernel_mode("auto"):
+        matmul(a2, b)              # Pallas
+        matmul(a3, b)              # 3-D: reference
+        jax.eval_shape(lambda *a: ops.ssd_decode(*a),
+                       *[jax.ShapeDtypeStruct(s, jnp.float32) for s in
+                         [(1, 2, 4), (1, 2), (2,), (1, 3), (1, 3), (2,),
+                          (1, 2, 4, 3)]])
+    with ops.kernel_mode("ref"):
+        matmul(a2, b)              # forced: not counted
+    try:
+        assert ops.dispatch_record() == {
+            "matmul": {"pallas": 1, "ref": 1},
+            "ssd_decode": {"pallas": 0, "ref": 1}}
+        assert ops.hidden_fallbacks() == ["matmul"]
+    finally:
+        ops.reset_dispatch_record()
+
+
+def test_seq_sp_steps_on_one_device_dispatch_no_reference(monkeypatch):
+    """phi4's seq_sp serve steps on a one-device mesh: the sequence offset
+    is a static 0, so prefill attention takes the flash kernel, and no op
+    with a kernel takes the reference (traced as if on TPU; eval_shape
+    traces without compiling)."""
+    from repro.configs import get_config
+    from repro.configs.base import ShapeConfig
+    from repro.core import collectives as col
+    from repro.kernels import ops
+    from repro.launch import steps
+    from repro.launch.mesh import make_mesh_for
+    assert col.axis_index(()) == 0
+    cfg = get_config("phi4-mini-3.8b").reduced()
+    mesh = make_mesh_for(1)
+    prefill = steps.make_prefill_step(
+        cfg, ShapeConfig("p", "prefill", 16, 2), mesh, max_seq=64,
+        with_sampling=True, compact_kv=True)
+    decode = steps.make_decode_step(
+        cfg, ShapeConfig("d", "decode", 64, 2), mesh, max_seq=64,
+        with_sampling=True, paged=(8, 16))
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    ops.reset_dispatch_record()
+    try:
+        for step in (prefill, decode):
+            jax.eval_shape(step.fn, *step.in_structs)
+        rec = ops.dispatch_record()
+        assert rec["flash_attention"] == {"pallas": 1, "ref": 0}
+        assert rec["paged_decode_partials"]["pallas"] == 1
+        assert ops.hidden_fallbacks() == []
+    finally:
+        ops.reset_dispatch_record()

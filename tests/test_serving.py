@@ -293,3 +293,32 @@ def test_zero_caches_compile_cached():
     zero_caches(st)
     zero_caches({"d": jax.ShapeDtypeStruct((4, 5), jnp.float32)})
     assert len(kv_mod._ZEROS_CACHE) == n
+
+
+def test_prefill_logits_argmax_is_greedy_token():
+    """lm.prefill_logits exposes the scores forward_prefill's greedy pick
+    takes the argmax of (the chip smoke test compares them across kernel
+    dispatch modes)."""
+    cfg, params = _phi4()
+    rng = np.random.default_rng(5)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab, (2, 11), dtype=np.int32))
+    z = lm.prefill_logits(params, {"tokens": toks}, plan=UNSHARDED,
+                          cfg=cfg, policy=FP32)
+    tok, _, _ = lm.forward_prefill(params, {"tokens": toks}, plan=UNSHARDED,
+                                   cfg=cfg, policy=FP32, max_seq=16)
+    assert z.shape == (2, cfg.padded_vocab) and z.dtype == jnp.float32
+    assert np.all(np.asarray(z)[:, cfg.vocab:] < -1e29)
+    np.testing.assert_array_equal(np.asarray(z).argmax(-1), np.asarray(tok))
+
+
+def test_device_lane_uploads_a_copy():
+    """The runner mutates its host lanes in place after dispatching a step;
+    the device arrays handed to that step must not alias them (on the CPU
+    backend `jnp.asarray` can share the numpy buffer, which made queued
+    steps read later values under load)."""
+    from repro.serving.sampling import device_lane
+    for n in range(1, 41):
+        lane = {"seed": np.zeros(n, np.int32)}
+        dev = device_lane(lane)
+        lane["seed"] += 7
+        assert int(np.asarray(dev["seed"]).max()) == 0, n

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.roofline import PEAKS
 from repro.configs import get_config
 from repro.core.precision import FP32
 from repro.models import lm
@@ -222,29 +223,61 @@ def test_spec_trace_annotations(model):
 
 def test_phase_util_and_trace_derivation_agree(traced_run):
     """stats.phase_util() (the counters) and derive_phase_metrics (the
-    trace) are two routes to the same per-phase MFU/MBU numbers."""
+    trace) are two routes to the same per-phase numbers.  The CPU is not
+    in the peak table, so both keep the counts and report MFU/MBU as
+    None ("not measured")."""
     _, engine, _ = traced_run
     tracer = engine.tracer
     st = engine.stats()
+    assert st.device_kind == jax.devices()[0].device_kind
+    assert PEAKS.get(st.device_kind) is None
     pu = st.phase_util()
     assert "prefill" in pu and "decode" in pu
     for row in pu.values():
-        assert row["mfu"] > 0 and row["mbu"] > 0
-        assert row["time_s"] > 0
+        assert row["time_s"] > 0 and row["tokens"] > 0
+        assert row["flops"] > 0 and row["hbm_bytes"] > 0
+        assert row["mfu"] is None and row["mbu"] is None
     derived = derive_phase_metrics(
         tracer.events,
         flops_per_token=st.model_flops_per_token,
         weight_bytes=st.weight_bytes_per_device,
-        kv_bytes_per_token=st.kv_bytes_per_token)
+        kv_bytes_per_token=st.kv_bytes_per_token,
+        peaks=PEAKS.get(st.device_kind))
     for phase in ("prefill", "decode"):
         assert phase in derived
-        for key in ("time_s", "tokens", "flops", "mfu", "mbu"):
+        for key in ("time_s", "tokens", "flops", "hbm_bytes"):
             assert derived[phase][key] == pytest.approx(
                 pu[phase][key], rel=1e-6), (phase, key)
+        assert derived[phase]["mfu"] is None
+        assert derived[phase]["mbu"] is None
     d = st.to_dict()
     assert d["phase_util"] == pu
     assert d["model_flops_per_token"] > 0
     assert d["kv_bytes_per_token"] > 0
+
+
+def test_phase_util_with_explicit_v5e_peaks(traced_run):
+    """Given a device's peaks explicitly, both routes report the same
+    positive MFU/MBU: achieved rate over the table's bf16 / HBM peak."""
+    _, engine, _ = traced_run
+    st = engine.stats()
+    v5e = PEAKS["TPU v5 lite"]
+    pu = st.phase_util(peaks=v5e)
+    derived = derive_phase_metrics(
+        engine.tracer.events,
+        flops_per_token=st.model_flops_per_token,
+        weight_bytes=st.weight_bytes_per_device,
+        kv_bytes_per_token=st.kv_bytes_per_token, peaks=v5e)
+    for phase in ("prefill", "decode"):
+        row = pu[phase]
+        assert row["mfu"] == pytest.approx(
+            row["flops"] / (row["time_s"] * v5e.bf16_flops), rel=1e-9)
+        assert row["mbu"] == pytest.approx(
+            row["hbm_bytes"] / (row["time_s"] * v5e.hbm_bw), rel=1e-9)
+        assert row["mfu"] > 0 and row["mbu"] > 0
+        for key in ("mfu", "mbu"):
+            assert derived[phase][key] == pytest.approx(row[key],
+                                                        rel=1e-6)
 
 
 def test_spec_engine_attributes_verify_phase(model):
@@ -252,7 +285,7 @@ def test_spec_engine_attributes_verify_phase(model):
     if spec_support_reason(cfg) is not None:
         pytest.skip(spec_support_reason(cfg))
     engine, _ = _run(cfg, params, spec=SpecConfig(draft="self", k=2))
-    pu = engine.stats().phase_util()
+    pu = engine.stats().phase_util(peaks=PEAKS["TPU v5 lite"])
     assert "verify" in pu and "decode" not in pu
     assert pu["verify"]["mfu"] > 0
 
@@ -294,7 +327,9 @@ def test_prometheus_text_snapshot(traced_run):
     _, engine, _ = traced_run
     text = prometheus_text(engine.stats().to_dict())
     assert "serving_ar_tok_s" in text
-    assert 'serving_phase_mfu{phase="decode"}' in text
+    assert 'serving_phase_flops{phase="decode"}' in text
+    # MFU on a device without peaks is "not measured": never exported
+    assert "serving_phase_mfu" not in text
     for line in text.splitlines():
         if line and not line.startswith("#"):
             name, val = line.rsplit(" ", 1)
