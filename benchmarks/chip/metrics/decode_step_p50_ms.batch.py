@@ -1,0 +1,2 @@
+"""Runner: median decode step time in the window (batch cells)."""
+from chipbench.readers import decode_step_p50_ms as read  # noqa: F401
