@@ -57,11 +57,8 @@ Three scheduler/runner-split scenarios ride along in `record["scenarios"]`:
                    schema-clean non-empty Chrome trace (--trace-out, the
                    CI artifact) and land within 5% of the untraced
                    goodput, or the bench exits nonzero (the overhead gate
-                   for the observability subsystem).  Per-phase MFU/MBU
-                   attribution rides along in the artifact: the base
-                   record's `phase_util` (from EngineStats.to_dict) and
-                   the traced run's `traced.phase_util`.  Pass
-                   --trace-dir to also capture per-scenario traces.
+                   for the observability subsystem).  Pass --trace-dir to
+                   also capture per-scenario traces.
 """
 from __future__ import annotations
 
@@ -664,7 +661,6 @@ def goodput_workload(cfg, params, args) -> dict:
         "slo_met": st.slo_met,
         "wall_s": wall,
         "goodput_rps": st.slo_met / wall if wall else 0.0,
-        "phase_util": st.phase_util(),
     }
     return out
 
@@ -936,12 +932,6 @@ def main(argv=None) -> int:
               f"({tr['slo_met']} met), {tr['trace_events']} events"
               f"{', ' + str(tr['trace_dropped']) + ' dropped' if tr['trace_dropped'] else ''}"
               f" -> {tr['trace_out'] or '(unwritten)'}")
-        for ph, row in tr["phase_util"].items():
-            mfu, mbu = (("not measured",) * 2 if row["mfu"] is None
-                        else (f"{row['mfu']:.2e}", f"{row['mbu']:.2e}"))
-            print(f"    {ph}: MFU {mfu} MBU {mbu} "
-                  f"({row['time_s'] * 1e3:.0f}ms, {row['tokens']:.0f} tok, "
-                  f"{row['passes']:.0f} passes)")
         problems = check_spec(spec_rec)
         problems += [f"TREE: {p}" for p in check_tree_spec(tree_rec)]
         problems += [f"PREFIX: {p}" for p in check_shared_prefix(prefix_rec)]

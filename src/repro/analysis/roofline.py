@@ -13,7 +13,7 @@ fraction = compute_term / max(all terms) is the MFU-style score (§Perf).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Optional, Tuple
+from typing import Dict
 
 from repro.analysis.hlo import HloSummary
 
@@ -120,28 +120,10 @@ def roofline_from_summary(s: HloSummary) -> Roofline:
 def decoder_flops_per_token(cfg) -> float:
     """Analytic forward FLOPs per token position: 2 * N_active, embedding
     tables excluded — the per-token factor `model_flops` scales by token
-    count, exposed on its own for the serving engine's per-phase MFU
-    attribution (serving/trace.py, EngineStats.phase_util)."""
+    count."""
     n = cfg.n_active_params()
     n -= cfg.padded_vocab * cfg.d_model * (1 if cfg.tie_embeddings else 2)
     return 2.0 * n
-
-
-def utilization(flops: float, mem_bytes: float, time_s: float,
-                peaks: Optional[DevicePeaks]
-                ) -> Tuple[Optional[float], Optional[float]]:
-    """(MFU, MBU) for an interval: achieved FLOP/s and HBM byte/s as a
-    fraction of the chip's bf16 and HBM peaks.  MFU = model FLOPs
-    utilization (analytic useful FLOPs / peak compute); MBU =
-    memory-bandwidth utilization (weight + KV traffic / peak HBM
-    bandwidth).  (None, None) when the device has no peaks ("not
-    measured"); (0, 0) on empty intervals."""
-    if peaks is None:
-        return None, None
-    if time_s <= 0:
-        return 0.0, 0.0
-    return (flops / (time_s * peaks.bf16_flops),
-            mem_bytes / (time_s * peaks.hbm_bw))
 
 
 def model_flops(cfg, shape) -> float:

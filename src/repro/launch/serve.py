@@ -263,14 +263,6 @@ def main(argv=None) -> int:
                   f"depth p50 {stats.spec_path_depth_p50:.1f} p95 "
                   f"{stats.spec_path_depth_p95:.1f}, branch utilization "
                   f"{stats.spec_branch_utilization:.0%}")
-    util = stats.phase_util()
-    if util:
-        def pct(x):
-            return "not measured" if x is None else f"{x:.2%}"
-        print(f"  util on {stats.device_kind}: " + " | ".join(
-            f"{ph} MFU {pct(row['mfu'])} MBU {pct(row['mbu'])} "
-            f"({row['time_s'] * 1e3:.0f}ms)"
-            for ph, row in util.items()))
     if tracer is not None:
         n_ev = tracer.write(args.trace_out)
         print(f"  trace: {n_ev} events -> {args.trace_out} "

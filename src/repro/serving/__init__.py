@@ -15,5 +15,5 @@ from repro.serving.stats import (EngineStats, Reservoir, percentile,
                                  percentiles)
 from repro.serving.tasks import (EncodeTask, GenerateTask, Rejection,
                                  Request, Task, TokenEvent, validate_task)
-from repro.serving.trace import (Tracer, derive_phase_metrics,
-                                 prometheus_text, validate_chrome_trace)
+from repro.serving.trace import (Tracer, prometheus_text,
+                                 validate_chrome_trace)

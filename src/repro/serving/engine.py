@@ -57,6 +57,7 @@ from repro.serving.runner import DecodeHandle, ModelRunner
 from repro.serving.sampling import validate_sampling
 from repro.serving.scheduler import FCFSPolicy, SchedulerPolicy
 from repro.serving.spec import SpecConfig
+from repro.serving import trace
 from repro.serving.stats import EngineStats
 from repro.serving.tasks import (EncodeTask, GenerateTask, Rejection,
                                  Request, Task, TokenEvent, validate_task)
@@ -98,10 +99,10 @@ class InferenceEngine:
         # pools int8 with per-block-per-head scales.  Both default to
         # lossless bf16.
         # `tracer` (serving/trace.py Tracer, or None) turns on opt-in
-        # structured tracing: request lifecycle spans and engine-step
-        # spans land in its ring buffer, exportable as a Chrome trace.
-        # Pure observer — with tracer=None every hook is one falsy
-        # branch and tokens are identical under all traffic.
+        # structured tracing: request lifecycle spans and the stage spans
+        # land in its ring buffer, exportable as a Chrome trace.  The
+        # stage spans reach a running jax.profiler session either way.
+        # Pure observer: tokens are identical under all traffic.
         # `overlap=True` switches to the async overlapped host loop: the
         # engine dispatches a decode step and runs host-side scheduling /
         # admission (and, in steady state, even the NEXT dispatch) before
@@ -144,6 +145,7 @@ class InferenceEngine:
         self._stats = self._fresh_stats()
         self._prefix_base = self._prefix_snapshot()
         self._t_last_decode: Optional[float] = None
+        self._step_n = 0                   # engine.step spans opened
 
     @staticmethod
     def _restore_draft(cfg: ModelConfig, params, spec: SpecConfig,
@@ -223,18 +225,6 @@ class InferenceEngine:
         st.kv_dtype = self.runner.kv_dtype
         st.weight_bytes_per_device = self.runner.weight_bytes_per_device()
         st.kv_pool_bytes = self.runner.kv_pool_bytes()
-        # per-token FLOP / byte constants and the device kind for
-        # phase_util()'s MFU / MBU attribution (analysis/roofline.py)
-        from repro.analysis.roofline import decoder_flops_per_token
-        st.model_flops_per_token = decoder_flops_per_token(self.runner.cfg)
-        st.device_kind = self.runner.device_kind
-        if self.runner.paged:
-            denom = (self.runner.layout.num_blocks
-                     * self.runner.layout.block_size)
-        else:
-            denom = self.runner.B * self.runner.max_seq
-        if denom > 0:
-            st.kv_bytes_per_token = st.kv_pool_bytes / denom
         return st
 
     # -- admission -----------------------------------------------------
@@ -495,35 +485,36 @@ class InferenceEngine:
         an UNCOMMITTED in-flight decode step (overlapped loop): their
         output/pos lag by one token, so the finished-check would both
         misjudge and drop the flying token — they retire after commit."""
-        runner = self.runner
-        pos = np.asarray(runner.pos)
-        for b, task in enumerate(runner.slots):
-            if task is None or runner.prefilling[b] or b in skip:
-                continue
-            tok = task.output[-1]
-            if (len(task.output) >= task.max_new_tokens
-                    or (task.eos_id is not None and tok == task.eos_id)
-                    or int(pos[b]) >= self.runner.max_seq - 1):
-                task.done = True
-                now = time.perf_counter()
-                task.latency_ms = (now - task._t_submit) * 1e3
-                n = len(task.output)
-                task.tpot_ms = ((task.latency_ms - task.ttft_ms) / (n - 1)
-                                if n > 1 else 0.0)
-                if n > 1:
-                    self._stats.add_tpot_ms(task.tpot_ms)
-                self._stats.record_slo(task)
-                self.completed.append(task)
-                self._stats.requests_completed += 1
-                if self.tracer:
-                    self.tracer.request_span(
-                        task.uid, "request", task._t_submit, now,
-                        ttft_ms=task.ttft_ms, tpot_ms=task.tpot_ms,
-                        latency_ms=task.latency_ms, tokens=n,
-                        prompt_len=task.prompt_len,
-                        degraded=task.degraded,
-                        cached_prefix=task.cached_prefix)
-                runner.release_slot(b)
+        with trace.span(trace.RETIRE, self.tracer):
+            runner = self.runner
+            pos = np.asarray(runner.pos)
+            for b, task in enumerate(runner.slots):
+                if task is None or runner.prefilling[b] or b in skip:
+                    continue
+                tok = task.output[-1]
+                if (len(task.output) >= task.max_new_tokens
+                        or (task.eos_id is not None and tok == task.eos_id)
+                        or int(pos[b]) >= self.runner.max_seq - 1):
+                    task.done = True
+                    now = time.perf_counter()
+                    task.latency_ms = (now - task._t_submit) * 1e3
+                    n = len(task.output)
+                    task.tpot_ms = ((task.latency_ms - task.ttft_ms)
+                                    / (n - 1) if n > 1 else 0.0)
+                    if n > 1:
+                        self._stats.add_tpot_ms(task.tpot_ms)
+                    self._stats.record_slo(task)
+                    self.completed.append(task)
+                    self._stats.requests_completed += 1
+                    if self.tracer:
+                        self.tracer.request_span(
+                            task.uid, "request", task._t_submit, now,
+                            ttft_ms=task.ttft_ms, tpot_ms=task.tpot_ms,
+                            latency_ms=task.latency_ms, tokens=n,
+                            prompt_len=task.prompt_len,
+                            degraded=task.degraded,
+                            cached_prefix=task.cached_prefix)
+                    runner.release_slot(b)
 
     # -- engine loop ------------------------------------------------------
     def step(self) -> List[TokenEvent]:
@@ -532,16 +523,19 @@ class InferenceEngine:
         (prefill first-tokens + decoded tokens), with `is_last` resolved
         against retirement.  With overlap=True the AR step's token fetch
         is deferred into the NEXT iteration so host scheduling work runs
-        while the device computes (token-identical either way)."""
-        if not self.tracer:
-            return self._step_inner()
-        t0 = time.perf_counter()
-        events = self._step_inner()
-        self.tracer.step_span(
-            "engine_step", t0, time.perf_counter(),
-            queued=len(self.queue), degrade=self._degrade,
-            running=sum(s is not None for s in self.runner.slots),
-            events=len(events))
+        while the device computes (token-identical either way).
+
+        Feeds `engine_steps` and `host_s`: the step's seconds less those
+        the runner spent blocked in token fetches."""
+        fetch0 = self.runner.fetch_s
+        with trace.span(trace.ENGINE_STEP, self.tracer, n=self._step_n,
+                        queued=len(self.queue),
+                        running=sum(s is not None
+                                    for s in self.runner.slots)) as sp:
+            events = self._step_inner()
+        self._step_n += 1
+        self._stats.engine_steps += 1
+        self._stats.host_s += sp.seconds - (self.runner.fetch_s - fetch0)
         return events
 
     def _step_inner(self) -> List[TokenEvent]:
@@ -586,23 +580,31 @@ class InferenceEngine:
                            task.done and i == len(task.output) - 1)
                 for task, i in fresh]
 
+    def _admit_until_settled(self, fresh: List):
+        """Admit/retire until slots are full or the queue drains: a request
+        finished by its prefill token alone (max_new_tokens=1, prompt-eos,
+        pos cap) frees its slot (and blocks) for another admission before
+        the AR step.  A free slot the pool cannot serve yet is not
+        progress — stop and let the decode/retire cycle free blocks."""
+        runner = self.runner
+        with trace.span(trace.ADMIT, self.tracer) as sp:
+            total = 0
+            while True:
+                n_done = len(self.completed)
+                admitted = self._admit(fresh)
+                total += admitted
+                self._retire()
+                if not self._gen_queue() or not runner.free_slots():
+                    break
+                if not admitted and len(self.completed) == n_done:
+                    break
+            sp.set(admitted=total)
+
     def _step_sync(self) -> List[TokenEvent]:
         runner = self.runner
         fresh: List = []                  # (task, output index) pairs
         self._run_encode()
-        # admit/retire until slots are full or the queue drains: a request
-        # finished by its prefill token alone (max_new_tokens=1, prompt-eos,
-        # pos cap) frees its slot (and blocks) for another admission before
-        # the AR step.  A free slot the pool cannot serve yet is not
-        # progress — stop and let the decode/retire cycle free blocks.
-        while True:
-            n_done = len(self.completed)
-            admitted = self._admit(fresh)
-            self._retire()
-            if not self._gen_queue() or not runner.free_slots():
-                break
-            if not admitted and len(self.completed) == n_done:
-                break
+        self._admit_until_settled(fresh)
         self._advance_chunks(fresh)
         # retire before decode: the final chunk's token may end its request
         self._retire()
@@ -614,9 +616,10 @@ class InferenceEngine:
             la = runner.spec_lookahead() if runner.spec else None
             # each eviction goes to the queue head (most recently evicted
             # first), matching the pre-split engine's re-queue order
-            for task in runner.ensure_decode_blocks(victim, self._stats,
-                                                    lookahead=la):
-                self.queue.insert(0, task)
+            with trace.span(trace.BLOCKS, self.tracer):
+                for task in runner.ensure_decode_blocks(victim, self._stats,
+                                                        lookahead=la):
+                    self.queue.insert(0, task)
             if runner.decoding_slots():
                 t0 = time.perf_counter()
                 if self._t_last_decode is not None:
@@ -700,29 +703,24 @@ class InferenceEngine:
         # retirement until the commit lands their token)
         self._run_encode()
         pend = self._pending_slots()
-        self._admit(fresh)
-        self._retire(skip=pend)
+        with trace.span(trace.ADMIT, self.tracer) as sp:
+            sp.set(admitted=self._admit(fresh))
+            self._retire(skip=pend)
         self._advance_chunks(fresh)
         if self._pending is not None:
             self._commit_pending(fresh)
         self._retire()
         # settle: slots freed by retirement admit more work this step,
         # exactly like the synchronous loop's admit/retire cycle
-        while True:
-            n_done = len(self.completed)
-            admitted = self._admit(fresh)
-            self._retire()
-            if not self._gen_queue() or not runner.free_slots():
-                break
-            if not admitted and len(self.completed) == n_done:
-                break
+        self._admit_until_settled(fresh)
         if runner.decoding_slots():
             victim = lambda running: self.scheduler.select_victim(
                 running, time.perf_counter())
             la = runner.spec_lookahead() if runner.spec else None
-            for task in runner.ensure_decode_blocks(victim, self._stats,
-                                                    lookahead=la):
-                self.queue.insert(0, task)
+            with trace.span(trace.BLOCKS, self.tracer):
+                for task in runner.ensure_decode_blocks(victim, self._stats,
+                                                        lookahead=la):
+                    self.queue.insert(0, task)
             if runner.decoding_slots():
                 t0 = time.perf_counter()
                 if self._t_last_decode is not None:

@@ -53,7 +53,8 @@ from repro.serving.kv_cache import (BlockAllocator, make_block_copy,
 from repro.models.quantize import quantize_params
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.sampling import (device_lane, set_lane, stack_lanes,
-                                    stack_prefill_lanes, zero_lane)
+                                    stack_prefill_lanes, upload, zero_lane)
+from repro.serving import trace
 from repro.serving.spec import (DraftState, SpecConfig, accept_length,
                                 accept_tree_path, build_tree, resolve_draft,
                                 round_annotation, spec_support_reason,
@@ -76,9 +77,8 @@ class DecodeHandle:
     live_tokens: int                            # post-step pos over decoding
     blocks_used: int                            # allocator.num_used at dispatch
     t_disp: float = 0.0                         # dispatch-return wall-clock
-    #                                             (set only when tracing: the
-    #                                             commit-side overlap lag is
-    #                                             t_fetch - t_disp)
+    #                                             (the commit-side overlap
+    #                                             lag is t_fetch - t_disp)
 
 
 def _device_nbytes(x) -> int:
@@ -109,11 +109,13 @@ class ModelRunner:
         assert weight_dtype in ("bfloat16", "int8"), weight_dtype
         assert kv_dtype in (None, "bfloat16", "int8"), kv_dtype
         self.cfg = cfg
-        # opt-in structured tracer (serving/trace.py).  Every hook below is
-        # behind one `if self.tracer:` branch — disabled tracing costs a
-        # single falsy check and observes nothing (token identity by
-        # construction: hooks never feed back into scheduling or sampling).
+        # opt-in ring tracer (serving/trace.py): the stage spans below
+        # also push to it, and request-row hooks sit behind `if
+        # self.tracer:`.  Pure observers: nothing they record feeds back
+        # into scheduling or sampling.
         self.tracer = tracer
+        # seconds spent blocked in "*.fetch" spans (host waits on the chip)
+        self.fetch_s = 0.0
         # weight-only int8 (models/quantize): the dense GEMM weights are
         # quantized ONCE here, per output channel; every compiled step then
         # streams int8 tiles and dequantizes inside the fp32 epilogue.
@@ -330,13 +332,6 @@ class ModelRunner:
         pools plus their scale leaves and any dense (ring / cross-attn /
         SSM) state."""
         return sum(_device_nbytes(x) for x in jax.tree.leaves(self.caches))
-
-    @property
-    def device_kind(self) -> str:
-        """`device_kind` of the devices the steps run on."""
-        devs = (self.mesh.devices.flat if self.mesh is not None
-                else jax.devices())
-        return next(iter(devs)).device_kind
 
     # -- capacity / bucket geometry ------------------------------------
     @property
@@ -574,13 +569,19 @@ class ModelRunner:
                                      blk[:n_full // bs])
 
     def _tables(self):
-        # every host -> device upload here is a copy (jnp.array): on the
-        # CPU backend jnp.asarray may alias the numpy buffer, and the host
-        # mirrors (tables, pos, tokens, lanes) are mutated in place while a
-        # dispatched step may not have read them yet
+        # the host mirrors (tables, pos, tokens, lanes) are written in place
+        # while a dispatched step may not have read them yet: `upload`
         if self._tables_dev is None:
-            self._tables_dev = jnp.array(self.block_tables)
+            self._tables_dev = upload(self.block_tables)
         return self._tables_dev
+
+    def _fetch(self, name: str, *arrays, **args):
+        """Host copies of device `arrays` inside a `*.fetch` stage span:
+        the host waits there for the chip.  -> (copies, span)."""
+        with trace.span(name, self.tracer, **args) as sp:
+            out = [np.asarray(a) for a in arrays]
+        self.fetch_s += sp.seconds
+        return out, sp
 
     def ensure_decode_blocks(
             self, select_victim: Callable[[Sequence[Task]], Task],
@@ -674,38 +675,41 @@ class ModelRunner:
         bucket = self.bucket_for(len(fulls[0]))
         n = len(tasks)
         step = self._prefill_for(bucket, n, stats)
-        t0 = time.perf_counter()
-        padded = np.zeros((n, bucket), np.int32)
-        for j, seq in enumerate(fulls):
-            padded[j, :len(seq)] = seq
-        batch = {"tokens": jnp.array(padded)}
-        if self.cfg.n_patches:
-            batch["patches"] = jnp.zeros(
-                (n, self.cfg.n_patches, self.cfg.d_model), jnp.bfloat16)
-        if self.cfg.enc_schedule:
-            batch["frames"] = jnp.zeros(
-                (n, self.cfg.enc_seq_padded, self.cfg.d_model), jnp.bfloat16)
-        tok, caches_g, pos_g = step.fn(
-            self.params, batch,
-            stack_prefill_lanes([t.sampling for t in tasks],
-                                [len(f) for f in fulls]))
-
         slots = free_slots[:n]
-        if self.paged:
-            tables = np.full((n, self.layout.max_blocks), -1, np.int32)
-            for j, (_, blk) in enumerate(group):
-                tables[j, :len(blk)] = blk
-        else:
-            tables = np.zeros((n, 1), np.int32)      # unused by the scatter
-        self.caches = self._scatter(self.caches, caches_g,
-                                    jnp.array(slots, jnp.int32),
-                                    jnp.array(tables))
-        tok_np = np.asarray(tok)
-        self.tokens[slots] = tok_np
-        self._tok_dev = None        # host token write: the chained device
-        #                             copy no longer matches the mirror
-        self.pos[slots] = np.asarray(pos_g)
-        now = time.perf_counter()
+        with trace.span(trace.PREFILL, self.tracer, bucket=bucket, group=n,
+                        tokens=sum(len(f) for f in fulls),
+                        uids=[t.uid for t in tasks]) as sp:
+            padded = np.zeros((n, bucket), np.int32)
+            for j, seq in enumerate(fulls):
+                padded[j, :len(seq)] = seq
+            batch = {"tokens": jnp.array(padded)}
+            if self.cfg.n_patches:
+                batch["patches"] = jnp.zeros(
+                    (n, self.cfg.n_patches, self.cfg.d_model), jnp.bfloat16)
+            if self.cfg.enc_schedule:
+                batch["frames"] = jnp.zeros(
+                    (n, self.cfg.enc_seq_padded, self.cfg.d_model),
+                    jnp.bfloat16)
+            tok, caches_g, pos_g = step.fn(
+                self.params, batch,
+                stack_prefill_lanes([t.sampling for t in tasks],
+                                    [len(f) for f in fulls]))
+            if self.paged:
+                tables = np.full((n, self.layout.max_blocks), -1, np.int32)
+                for j, (_, blk) in enumerate(group):
+                    tables[j, :len(blk)] = blk
+            else:
+                tables = np.zeros((n, 1), np.int32)  # unused by the scatter
+            self.caches = self._scatter(self.caches, caches_g,
+                                        jnp.array(slots, jnp.int32),
+                                        jnp.array(tables))
+            (tok_np, pos_np), fetch = self._fetch(trace.PREFILL_FETCH, tok,
+                                                  pos_g)
+            self.tokens[slots] = tok_np
+            self._tok_dev = None    # host token write: the chained device
+            #                         copy no longer matches the mirror
+            self.pos[slots] = pos_np
+        t0, now = sp.t0, fetch.t1
         dt_ms = (now - t0) * 1e3
 
         fresh: List[Tuple[GenerateTask, int]] = []
@@ -741,12 +745,6 @@ class ModelRunner:
         stats.nar_time_s += (now - t0) * n_first / n
         stats.recompute_time_s += (now - t0) * (n - n_first) / n
         stats.prefill_batches += 1
-        if self.tracer:
-            self.tracer.step_span(
-                "prefill", t0, now, phase="prefill", bucket=bucket, group=n,
-                tokens=bucket * n, kv_positions=sum(len(f) for f in fulls),
-                passes=1, busy_ms=(now - t0) * 1e3,
-                uids=[t.uid for t in tasks])
         if self.spec is not None:
             self._draft_prefill(fulls, slots, stats)
         return fresh
@@ -791,7 +789,9 @@ class ModelRunner:
         self.draft_caches = self._draft_scatter(
             self.draft_caches, dcaches, jnp.array(slots, jnp.int32),
             jnp.zeros((n, 1), jnp.int32))
-        jax.block_until_ready(self.draft_caches)   # honest overhead timing
+        with trace.span(trace.SPEC_DRAFT_FETCH, self.tracer) as sp:
+            jax.block_until_ready(self.draft_caches)   # honest timing
+        self.fetch_s += sp.seconds
         for j, b in enumerate(slots):
             self.draft_states[b] = DraftState(pos=len(fulls[j]))
         stats.spec_draft_time_s += time.perf_counter() - t0
@@ -817,25 +817,28 @@ class ModelRunner:
         full = self.full_prompt(task)
         start = task.prefilled
         step = self._chunk_for(chunk_tokens)
-        t0 = time.perf_counter()
         C = chunk_tokens
         take = min(C, len(full) - start)
-        chunk = np.zeros((1, C), np.int32)
-        chunk[0, :take] = full[start:start + take]
-        row_table = np.full((1, self.layout.max_blocks), -1, np.int32)
-        row_table[0, :len(self._slot_blocks[b])] = self._slot_blocks[b]
-        lane = stack_lanes([task.sampling])
-        tok, self.caches, pos_end = step.fn(
-            self.params, jnp.array(chunk),
-            jnp.array([start], jnp.int32),
-            jnp.array([take], jnp.int32),
-            self.caches, jnp.array(row_table), lane)
-        tok_np = int(np.asarray(tok)[0])          # blocks: honest timing
-        pos_np = int(np.asarray(pos_end)[0])
-        now = time.perf_counter()
+        first_admit = not task.output
+        with trace.span(trace.CHUNK, self.tracer, width=C, pos0=start,
+                        take=take, uids=[task.uid],
+                        recompute=not first_admit) as sp:
+            chunk = np.zeros((1, C), np.int32)
+            chunk[0, :take] = full[start:start + take]
+            row_table = np.full((1, self.layout.max_blocks), -1, np.int32)
+            row_table[0, :len(self._slot_blocks[b])] = self._slot_blocks[b]
+            lane = stack_lanes([task.sampling])
+            tok, self.caches, pos_end = step.fn(
+                self.params, jnp.array(chunk),
+                jnp.array([start], jnp.int32),
+                jnp.array([take], jnp.int32),
+                self.caches, jnp.array(row_table), lane)
+            (tok_np, pos_np), fetch = self._fetch(trace.CHUNK_FETCH, tok,
+                                                  pos_end)
+        tok_np, pos_np = int(tok_np[0]), int(pos_np[0])
+        t0, now = sp.t0, fetch.t1
         task.prefilled = start + take
         task.prefill_ms += (now - t0) * 1e3
-        first_admit = not task.output
         if first_admit:
             stats.nar_tokens += take
             stats.padded_nar_tokens += C
@@ -845,12 +848,6 @@ class ModelRunner:
             stats.recompute_time_s += now - t0
         stats.prefill_chunks += 1
         stats.chunked_prefill_tokens += take
-        if self.tracer:
-            self.tracer.step_span(
-                "prefill_chunk", t0, now, phase="prefill", uid=task.uid,
-                tokens=C, true_tokens=take, kv_positions=take, passes=1,
-                busy_ms=(now - t0) * 1e3, pos0=start,
-                recompute=not first_admit)
         if task.prefilled < len(full):
             return None
         # final chunk: the sampled token is the prompt's first output and
@@ -886,36 +883,33 @@ class ModelRunner:
         `self.pos += 1` is exact, and the returned token future is kept as
         `_tok_dev` so a back-to-back dispatch chains on it device-side
         instead of re-uploading the host mirror."""
-        t0 = time.perf_counter()
-        tok_in = (self._tok_dev if self._tok_dev is not None
-                  else jnp.array(self.tokens))
-        pos_d = jnp.array(self.pos)
-        lane_d = device_lane(self.lane)
-        if self.paged:
-            tok_d, _, self.caches = self.decode_step.fn(
-                self.params, tok_in, pos_d, self.caches,
-                self._tables(), lane_d)
-        else:
-            tok_d, _, self.caches = self.decode_step.fn(
-                self.params, tok_in, pos_d, self.caches, lane_d)
-        self._tok_dev = tok_d
-        start_d2h = getattr(tok_d, "copy_to_host_async", None)
-        if start_d2h is not None:
-            start_d2h()     # non-blocking device_get: the commit's fetch
-            #                 finds the bytes already on their way
-        self.pos += 1
-        self.steps_run += 1
         decoding = [(b, self.slots[b]) for b in self.decoding_slots()]
-        live = sum(int(self.pos[b]) for b, _ in decoding)
-        handle = DecodeHandle(
-            tok_d, t0, decoding, live,
-            self.allocator.num_used if self.paged else 0)
-        if self.tracer:
-            handle.t_disp = time.perf_counter()
-            self.tracer.step_span(
-                "decode_dispatch", t0, handle.t_disp, slots=len(decoding),
-                uids=[t.uid for _, t in decoding])
-        return handle
+        # a slot whose input sits at position p attends p + 1 positions
+        live = sum(int(self.pos[b]) + 1 for b, _ in decoding)
+        with trace.span(trace.DECODE_DISPATCH, self.tracer,
+                        slots=len(decoding), kv_positions=live,
+                        uids=[t.uid for _, t in decoding]) as sp:
+            tok_in = (self._tok_dev if self._tok_dev is not None
+                      else upload(self.tokens))
+            pos_d = upload(self.pos)
+            lane_d = device_lane(self.lane)
+            if self.paged:
+                tok_d, _, self.caches = self.decode_step.fn(
+                    self.params, tok_in, pos_d, self.caches,
+                    self._tables(), lane_d)
+            else:
+                tok_d, _, self.caches = self.decode_step.fn(
+                    self.params, tok_in, pos_d, self.caches, lane_d)
+            self._tok_dev = tok_d
+            start_d2h = getattr(tok_d, "copy_to_host_async", None)
+            if start_d2h is not None:
+                start_d2h()     # non-blocking device_get: the commit's fetch
+                #                 finds the bytes already on their way
+            self.pos += 1
+            self.steps_run += 1
+        return DecodeHandle(tok_d, sp.t0, decoding, live,
+                            self.allocator.num_used if self.paged else 0,
+                            t_disp=sp.t1)
 
     def decode_commit(self, handle: DecodeHandle, stats: EngineStats,
                       ) -> List[Tuple[GenerateTask, int]]:
@@ -923,43 +917,34 @@ class ModelRunner:
         the host mirrors, task outputs and stats.  Under the overlapped
         loop the elapsed-time sample is floored at the previous commit so
         back-to-back pipelined steps don't double-count wall time."""
-        tr = self.tracer
-        t_fetch = time.perf_counter() if tr else 0.0
-        toks = np.asarray(handle.tok_d)           # blocks: honest timing
-        now = time.perf_counter()
+        # overlap_lag_ms: host wall between dispatch returning and this
+        # fetch starting, scheduling work the device step hid
+        (toks,), fetch = self._fetch(
+            trace.DECODE_FETCH, handle.tok_d, overlap_lag_ms=max(
+                0.0, (time.perf_counter() - handle.t_disp) * 1e3))
+        now = fetch.t1
         floor = self._t_last_commit
         dt = now - (max(handle.t0, floor) if floor is not None
                     else handle.t0)
         self._t_last_commit = now
         fresh: List[Tuple[GenerateTask, int]] = []
-        for b, task in handle.decoding:
-            tok = int(toks[b])
-            if self.slots[b] is task:
-                self.tokens[b] = tok    # mirror update, not a host write:
-                #                         _tok_dev stays valid
-            task.output.append(tok)
-            task.decode_ms += dt * 1e3
-            fresh.append((task, len(task.output) - 1))
-        stats.decode_steps += 1
-        stats.ar_tokens += len(handle.decoding)
-        stats.ar_time_s += dt
-        stats.add_decode_step_ms(dt * 1e3)
-        stats.occupied_slot_steps += len(handle.decoding)
-        if self.paged:
-            stats.block_slot_steps += handle.blocks_used
-            stats.token_slot_steps += handle.live_tokens
-        if tr:
-            ann = {}
-            if handle.t_disp:
-                # host wall between dispatch returning and the commit-side
-                # fetch starting: scheduling work the device step hid
-                ann["overlap_lag_ms"] = max(
-                    0.0, (t_fetch - handle.t_disp) * 1e3)
-            tr.step_span(
-                "decode_step", handle.t0, now, phase="decode",
-                slots=len(handle.decoding), tokens=len(handle.decoding),
-                kv_positions=handle.live_tokens, passes=1, busy_ms=dt * 1e3,
-                uids=[t.uid for _, t in handle.decoding], **ann)
+        with trace.span(trace.DECODE_COMMIT, self.tracer):
+            for b, task in handle.decoding:
+                tok = int(toks[b])
+                if self.slots[b] is task:
+                    self.tokens[b] = tok    # mirror update, not a host
+                    #                         write: _tok_dev stays valid
+                task.output.append(tok)
+                task.decode_ms += dt * 1e3
+                fresh.append((task, len(task.output) - 1))
+            stats.decode_steps += 1
+            stats.ar_tokens += len(handle.decoding)
+            stats.ar_time_s += dt
+            stats.add_decode_step_ms(dt * 1e3)
+            stats.occupied_slot_steps += len(handle.decoding)
+            if self.paged:
+                stats.block_slot_steps += handle.blocks_used
+                stats.token_slot_steps += handle.live_tokens
         return fresh
 
     def decode(self, stats: EngineStats) -> List[Tuple[GenerateTask, int]]:
@@ -1099,115 +1084,109 @@ class ModelRunner:
             known[b] = [self._token_at(self.slots[b], p)
                         for p in range(ds.pos, int(pos0[b]) + 1)]
         n_steps = max(max(len(known[b]) - 1 + int(la[b]) for b in active), 1)
-        t0 = time.perf_counter()
-        lane_d = device_lane(self.lane)
-        feed = np.zeros((self.B,), np.int32)
-        proposals: Dict[int, List[int]] = {b: [] for b in active}
-        last_out = np.zeros((self.B,), np.int32)
-        for s in range(n_steps):
-            for b in active:
-                feed[b] = (known[b][s] if s < len(known[b])
-                           else int(last_out[b]))
-            out_d, _, self.draft_caches = self.draft_decode_step.fn(
-                self.draft_params, jnp.array(feed),
-                jnp.array(starts + s, jnp.int32), self.draft_caches,
-                lane_d)
-            last_out = np.asarray(out_d)
-            for b in active:
-                if (s >= len(known[b]) - 1
-                        and len(proposals[b]) < int(la[b])):
-                    proposals[b].append(int(last_out[b]))
-        t_draft = time.perf_counter() - t0
+        with trace.span(trace.SPEC_DRAFT, self.tracer, steps=n_steps,
+                        slots=len(active)) as sp:
+            lane_d = device_lane(self.lane)
+            feed = np.zeros((self.B,), np.int32)
+            proposals: Dict[int, List[int]] = {b: [] for b in active}
+            last_out = np.zeros((self.B,), np.int32)
+            for s in range(n_steps):
+                for b in active:
+                    feed[b] = (known[b][s] if s < len(known[b])
+                               else int(last_out[b]))
+                out_d, _, self.draft_caches = self.draft_decode_step.fn(
+                    self.draft_params, jnp.array(feed),
+                    jnp.array(starts + s, jnp.int32), self.draft_caches,
+                    lane_d)
+                (last_out,), _ = self._fetch(trace.SPEC_DRAFT_FETCH, out_d)
+                for b in active:
+                    if (s >= len(known[b]) - 1
+                            and len(proposals[b]) < int(la[b])):
+                        proposals[b].append(int(last_out[b]))
+        t_draft = sp.seconds
         stats.spec_draft_time_s += t_draft
         stats.add_draft_time_ms(t_draft * 1e3)
-        if self.tracer:
-            self.tracer.step_span(
-                "spec_draft", t0, t0 + t_draft, phase="draft",
-                steps=n_steps, slots=len(active), busy_ms=t_draft * 1e3)
 
-        # -- verify: target forwards [pending token, d_1..d_ke] into the
-        # slot's paged blocks, returning its own choice at every position
-        chunk = np.zeros((self.B, C), np.int32)
-        chunk_len = np.zeros((self.B,), np.int32)
-        for b in active:
-            chunk[b, 0] = self.tokens[b]
-            props = proposals[b]
-            chunk[b, 1:1 + len(props)] = props
-            chunk_len[b] = 1 + len(props)
-        t1 = time.perf_counter()
-        choices_d, self.caches, _ = self.verify_step.fn(
-            self.params, jnp.array(chunk), jnp.array(pos0, jnp.int32),
-            jnp.array(chunk_len), self.caches, self._tables(), lane_d)
-        choices = np.asarray(choices_d)           # blocks: honest timing
-        dt = time.perf_counter() - t1
-        self.steps_run += 1
+        with trace.span(trace.SPEC_VERIFY, self.tracer) as sp:
+            # -- verify: target forwards [pending token, d_1..d_ke] into the
+            # slot's paged blocks, returning its own choice at every position
+            chunk = np.zeros((self.B, C), np.int32)
+            chunk_len = np.zeros((self.B,), np.int32)
+            for b in active:
+                chunk[b, 0] = self.tokens[b]
+                props = proposals[b]
+                chunk[b, 1:1 + len(props)] = props
+                chunk_len[b] = 1 + len(props)
+            t1 = time.perf_counter()
+            choices_d, self.caches, _ = self.verify_step.fn(
+                self.params, jnp.array(chunk), jnp.array(pos0, jnp.int32),
+                jnp.array(chunk_len), self.caches, self._tables(), lane_d)
+            (choices,), fetch = self._fetch(trace.SPEC_VERIFY_FETCH, choices_d)
+            dt = fetch.t1 - t1
+            self.steps_run += 1
 
-        # -- commit + rollback
-        fresh: List[Tuple[GenerateTask, int]] = []
-        occupied = live_tokens = emitted_total = 0
-        round_proposed = round_accepted = 0
-        for b in active:
-            task = self.slots[b]
-            occupied += 1
-            ke = len(proposals[b])
-            cand = [int(c) for c in choices[b, :ke + 1]]
-            j = accept_length(proposals[b], cand)
-            stats.spec_proposed_tokens += ke
-            stats.spec_accepted_tokens += j
-            round_proposed += ke
-            round_accepted += j
-            # commit c_0..c_j, clamped to step-by-step retirement
-            # semantics (max_new / max_seq budget, cut at the first EOS)
-            room = min(task.max_new_tokens - len(task.output),
-                       self.max_seq - 1 - int(pos0[b]))
-            emitted = trim_emitted(cand[:j + 1], room=room,
-                                   eos_id=task.eos_id)
-            for tok in emitted:
-                task.output.append(tok)
-                fresh.append((task, len(task.output) - 1))
-            m = len(emitted)
-            emitted_total += m
-            pos_new = int(pos0[b]) + m
-            self.tokens[b] = emitted[-1]
-            self._tok_dev = None    # host token write invalidates the chain
-            self.pos[b] = pos_new
-            task.decode_ms += dt * 1e3
-            live_tokens += pos_new
-            # rollback: free blocks holding only rejected-token KV (the
-            # garbage inside kept blocks sits beyond pos and is masked,
-            # then overwritten as decoding advances)
-            keep = self.allocator.blocks_for(pos_new)
-            if len(self._slot_blocks[b]) > keep:
-                extra = self._slot_blocks[b][keep:]
-                del self._slot_blocks[b][keep:]
-                self.allocator.free(extra)
-                self.block_tables[b, keep:] = -1
-                self._tables_dev = None
-            # draft rewind: valid through the last draft-cache position
-            # whose written token matches the committed sequence (and
-            # never past the committed horizon)
-            self.draft_states[b].pos = min(int(starts[b]) + n_steps,
-                                           int(pos0[b]) + j + 1, pos_new)
-        stats.decode_steps += 1
-        stats.spec_rounds += 1
-        stats.spec_slot_steps += occupied
-        stats.spec_emitted_tokens += emitted_total
-        stats.ar_tokens += emitted_total
-        stats.ar_time_s += dt
-        stats.add_decode_step_ms(dt * 1e3)
-        stats.occupied_slot_steps += occupied
-        stats.block_slot_steps += self.allocator.num_used
-        stats.token_slot_steps += live_tokens
-        executed = int(chunk_len.sum())
-        stats.verify_positions += executed
-        if self.tracer:
-            self.tracer.step_span(
-                "spec_verify", t1, t1 + dt, phase="verify", tokens=executed,
-                kv_positions=live_tokens, passes=1, busy_ms=dt * 1e3,
-                slots=occupied,
-                **round_annotation(proposed=round_proposed,
-                                   accepted=round_accepted,
-                                   emitted=emitted_total))
+            # -- commit + rollback
+            fresh: List[Tuple[GenerateTask, int]] = []
+            occupied = live_tokens = emitted_total = 0
+            round_proposed = round_accepted = 0
+            for b in active:
+                task = self.slots[b]
+                occupied += 1
+                ke = len(proposals[b])
+                cand = [int(c) for c in choices[b, :ke + 1]]
+                j = accept_length(proposals[b], cand)
+                stats.spec_proposed_tokens += ke
+                stats.spec_accepted_tokens += j
+                round_proposed += ke
+                round_accepted += j
+                # commit c_0..c_j, clamped to step-by-step retirement
+                # semantics (max_new / max_seq budget, cut at the first EOS)
+                room = min(task.max_new_tokens - len(task.output),
+                           self.max_seq - 1 - int(pos0[b]))
+                emitted = trim_emitted(cand[:j + 1], room=room,
+                                       eos_id=task.eos_id)
+                for tok in emitted:
+                    task.output.append(tok)
+                    fresh.append((task, len(task.output) - 1))
+                m = len(emitted)
+                emitted_total += m
+                pos_new = int(pos0[b]) + m
+                self.tokens[b] = emitted[-1]
+                self._tok_dev = None    # host token write: chain invalid
+                self.pos[b] = pos_new
+                task.decode_ms += dt * 1e3
+                live_tokens += pos_new
+                # rollback: free blocks holding only rejected-token KV (the
+                # garbage inside kept blocks sits beyond pos and is masked,
+                # then overwritten as decoding advances)
+                keep = self.allocator.blocks_for(pos_new)
+                if len(self._slot_blocks[b]) > keep:
+                    extra = self._slot_blocks[b][keep:]
+                    del self._slot_blocks[b][keep:]
+                    self.allocator.free(extra)
+                    self.block_tables[b, keep:] = -1
+                    self._tables_dev = None
+                # draft rewind: valid through the last draft-cache position
+                # whose written token matches the committed sequence (and
+                # never past the committed horizon)
+                self.draft_states[b].pos = min(int(starts[b]) + n_steps,
+                                               int(pos0[b]) + j + 1, pos_new)
+            stats.decode_steps += 1
+            stats.spec_rounds += 1
+            stats.spec_slot_steps += occupied
+            stats.spec_emitted_tokens += emitted_total
+            stats.ar_tokens += emitted_total
+            stats.ar_time_s += dt
+            stats.add_decode_step_ms(dt * 1e3)
+            stats.occupied_slot_steps += occupied
+            stats.block_slot_steps += self.allocator.num_used
+            stats.token_slot_steps += live_tokens
+            executed = int(chunk_len.sum())
+            stats.verify_positions += executed
+            sp.set(slots=occupied,
+                   **round_annotation(proposed=round_proposed,
+                                      accepted=round_accepted,
+                                      emitted=emitted_total))
         return fresh
 
     def _spec_decode_tree(self, stats: EngineStats
@@ -1252,156 +1231,151 @@ class ModelRunner:
                         for p in range(ds.pos, int(pos0[b]) + 1)]
         n_steps = max(max(len(known[b]) - 1 + int(depth_la[b])
                           for b in active), 1)
-        t0 = time.perf_counter()
-        lane_d = device_lane(self.lane)
-        feed = np.zeros((self.B,), np.int32)
-        levels: Dict[int, List[List[int]]] = {b: [] for b in active}
-        last_out = np.zeros((self.B,), np.int32)
-        for s in range(n_steps):
-            for b in active:
-                feed[b] = (known[b][s] if s < len(known[b])
-                           else int(last_out[b]))
-            out_d, alts_d, _, self.draft_caches = self.draft_topk_step.fn(
-                self.draft_params, jnp.array(feed),
-                jnp.array(starts + s, jnp.int32), self.draft_caches,
-                lane_d)
-            last_out = np.asarray(out_d)
-            alts = np.asarray(alts_d)
-            for b in active:
-                if (s >= len(known[b]) - 1
-                        and len(levels[b]) < int(depth_la[b])):
-                    # alts[b, 0] == the chain token fed next step, by
-                    # sample_topn construction
-                    levels[b].append([int(t)
-                                      for t in alts[b, :int(width[b])]])
-        t_draft = time.perf_counter() - t0
+        with trace.span(trace.SPEC_DRAFT, self.tracer, steps=n_steps,
+                        slots=len(active)) as sp:
+            lane_d = device_lane(self.lane)
+            feed = np.zeros((self.B,), np.int32)
+            levels: Dict[int, List[List[int]]] = {b: [] for b in active}
+            last_out = np.zeros((self.B,), np.int32)
+            for s in range(n_steps):
+                for b in active:
+                    feed[b] = (known[b][s] if s < len(known[b])
+                               else int(last_out[b]))
+                out_d, alts_d, _, self.draft_caches = self.draft_topk_step.fn(
+                    self.draft_params, jnp.array(feed),
+                    jnp.array(starts + s, jnp.int32), self.draft_caches,
+                    lane_d)
+                (last_out, alts), _ = self._fetch(trace.SPEC_DRAFT_FETCH,
+                                                  out_d, alts_d)
+                for b in active:
+                    if (s >= len(known[b]) - 1
+                            and len(levels[b]) < int(depth_la[b])):
+                        # alts[b, 0] == the chain token fed next step, by
+                        # sample_topn construction
+                        levels[b].append([int(t)
+                                          for t in alts[b, :int(width[b])]])
+        t_draft = sp.seconds
         stats.spec_draft_time_s += t_draft
         stats.add_draft_time_ms(t_draft * 1e3)
-        if self.tracer:
-            self.tracer.step_span(
-                "spec_draft", t0, t0 + t_draft, phase="draft",
-                steps=n_steps, slots=len(active), busy_ms=t_draft * 1e3)
 
-        # -- verify: one tree-masked target pass over every slot's tree
-        chunk = np.zeros((self.B, C), np.int32)
-        chunk_len = np.zeros((self.B,), np.int32)
-        depth_op = np.zeros((self.B, C), np.int32)
-        anc_op = np.zeros((self.B, C, C), bool)
-        trees = {}
-        for b in active:
-            tree = build_tree(int(self.tokens[b]), levels[b])
-            trees[b] = tree
-            n = tree.n_nodes
-            chunk[b, :n] = tree.tokens
-            depth_op[b, :n] = tree.depth
-            anc_op[b, :n, :n] = tree.anc
-            chunk_len[b] = n
-        t1 = time.perf_counter()
-        choices_d, self.caches, _ = self.tree_verify_step.fn(
-            self.params, jnp.array(chunk), jnp.array(pos0, jnp.int32),
-            jnp.array(chunk_len), jnp.array(depth_op),
-            jnp.array(anc_op), self.caches, self._tables(), lane_d)
-        choices = np.asarray(choices_d)           # blocks: honest timing
-        dt = time.perf_counter() - t1
-        self.steps_run += 1
+        with trace.span(trace.SPEC_VERIFY, self.tracer) as sp:
+            # -- verify: one tree-masked target pass over every slot's tree
+            chunk = np.zeros((self.B, C), np.int32)
+            chunk_len = np.zeros((self.B,), np.int32)
+            depth_op = np.zeros((self.B, C), np.int32)
+            anc_op = np.zeros((self.B, C, C), bool)
+            trees = {}
+            for b in active:
+                tree = build_tree(int(self.tokens[b]), levels[b])
+                trees[b] = tree
+                n = tree.n_nodes
+                chunk[b, :n] = tree.tokens
+                depth_op[b, :n] = tree.depth
+                anc_op[b, :n, :n] = tree.anc
+                chunk_len[b] = n
+            t1 = time.perf_counter()
+            choices_d, self.caches, _ = self.tree_verify_step.fn(
+                self.params, jnp.array(chunk), jnp.array(pos0, jnp.int32),
+                jnp.array(chunk_len), jnp.array(depth_op),
+                jnp.array(anc_op), self.caches, self._tables(), lane_d)
+            (choices,), fetch = self._fetch(trace.SPEC_VERIFY_FETCH, choices_d)
+            dt = fetch.t1 - t1
+            self.steps_run += 1
 
-        # -- commit + compact + rollback
-        fresh: List[Tuple[GenerateTask, int]] = []
-        occupied = live_tokens = emitted_total = 0
-        round_proposed = round_accepted = round_nodes = round_branch = 0
-        path_depths: List[int] = []
-        bs = self.layout.block_size
-        for b in active:
-            task = self.slots[b]
-            occupied += 1
-            tree = trees[b]
-            n = tree.n_nodes
-            path = accept_tree_path(tree.tokens, tree.parent, choices[b], n)
-            stats.spec_proposed_tokens += n - 1
-            stats.spec_accepted_tokens += len(path)
-            stats.spec_tree_nodes += n
-            stats.add_spec_path_depth(len(path))
-            round_proposed += n - 1
-            round_accepted += len(path)
-            round_nodes += n
-            path_depths.append(len(path))
-            if any(not tree.chain[i] for i in path):
-                stats.spec_branch_hits += 1
-                round_branch += 1
-            full = [0] + path
-            cand = [int(choices[b, i]) for i in full]
-            room = min(task.max_new_tokens - len(task.output),
-                       self.max_seq - 1 - int(pos0[b]))
-            emitted = trim_emitted(cand, room=room, eos_id=task.eos_id)
-            m = len(emitted)
-            # compact: committed position pos0 + d must hold the KV that
-            # node full[d] wrote at pos0 + full[d] (already roped at its
-            # logical position pos0 + d).  full[] is strictly increasing
-            # with full[d] >= d, so ascending-d moves never clobber a
-            # pending source.
-            blks = self._slot_blocks[b]
-            for d in range(1, m):
-                if int(full[d]) == d:
-                    continue
-                src_p = int(pos0[b]) + int(full[d])
-                dst_p = int(pos0[b]) + d
-                self.caches = self._row_copy(
-                    self.caches, blks[src_p // bs], src_p % bs,
-                    blks[dst_p // bs], dst_p % bs)
-            for tok in emitted:
-                task.output.append(tok)
-                fresh.append((task, len(task.output) - 1))
-            emitted_total += m
-            pos_new = int(pos0[b]) + m
-            self.tokens[b] = emitted[-1]
-            self._tok_dev = None    # host token write invalidates the chain
-            self.pos[b] = pos_new
-            task.decode_ms += dt * 1e3
-            live_tokens += pos_new
-            # rollback: free blocks holding only rejected-node KV
-            keep = self.allocator.blocks_for(pos_new)
-            if len(self._slot_blocks[b]) > keep:
-                extra = self._slot_blocks[b][keep:]
-                del self._slot_blocks[b][keep:]
-                self.allocator.free(extra)
-                self.block_tables[b, keep:] = -1
-                self._tables_dev = None
-            # draft rewind: the dense draft cache followed the CHAIN, so
-            # it stays valid through the accepted path's leading chain
-            # prefix only (a sibling acceptance diverges from what the
-            # draft fed itself)
-            j_chain = 0
-            for i in path:
-                if not tree.chain[i]:
-                    break
-                j_chain += 1
-            self.draft_states[b].pos = min(int(starts[b]) + n_steps,
-                                           int(pos0[b]) + j_chain + 1,
-                                           pos_new)
-        self._round_depth = self._round_width = None
-        stats.decode_steps += 1
-        stats.spec_rounds += 1
-        stats.spec_slot_steps += occupied
-        stats.spec_emitted_tokens += emitted_total
-        stats.ar_tokens += emitted_total
-        stats.ar_time_s += dt
-        stats.add_decode_step_ms(dt * 1e3)
-        stats.occupied_slot_steps += occupied
-        stats.block_slot_steps += self.allocator.num_used
-        stats.token_slot_steps += live_tokens
-        executed = int(chunk_len.sum())
-        stats.verify_positions += executed
-        if self.tracer:
-            self.tracer.step_span(
-                "spec_verify", t1, t1 + dt, phase="verify", tokens=executed,
-                kv_positions=live_tokens, passes=1, busy_ms=dt * 1e3,
-                slots=occupied,
-                **round_annotation(proposed=round_proposed,
-                                   accepted=round_accepted,
-                                   emitted=emitted_total,
-                                   tree_nodes=round_nodes,
-                                   path_depths=path_depths,
-                                   branch_hits=round_branch))
+            # -- commit + compact + rollback
+            fresh: List[Tuple[GenerateTask, int]] = []
+            occupied = live_tokens = emitted_total = 0
+            round_proposed = round_accepted = round_nodes = round_branch = 0
+            path_depths: List[int] = []
+            bs = self.layout.block_size
+            for b in active:
+                task = self.slots[b]
+                occupied += 1
+                tree = trees[b]
+                n = tree.n_nodes
+                path = accept_tree_path(tree.tokens, tree.parent,
+                                        choices[b], n)
+                stats.spec_proposed_tokens += n - 1
+                stats.spec_accepted_tokens += len(path)
+                stats.spec_tree_nodes += n
+                stats.add_spec_path_depth(len(path))
+                round_proposed += n - 1
+                round_accepted += len(path)
+                round_nodes += n
+                path_depths.append(len(path))
+                if any(not tree.chain[i] for i in path):
+                    stats.spec_branch_hits += 1
+                    round_branch += 1
+                full = [0] + path
+                cand = [int(choices[b, i]) for i in full]
+                room = min(task.max_new_tokens - len(task.output),
+                           self.max_seq - 1 - int(pos0[b]))
+                emitted = trim_emitted(cand, room=room, eos_id=task.eos_id)
+                m = len(emitted)
+                # compact: committed position pos0 + d must hold the KV that
+                # node full[d] wrote at pos0 + full[d] (already roped at its
+                # logical position pos0 + d).  full[] is strictly increasing
+                # with full[d] >= d, so ascending-d moves never clobber a
+                # pending source.
+                blks = self._slot_blocks[b]
+                for d in range(1, m):
+                    if int(full[d]) == d:
+                        continue
+                    src_p = int(pos0[b]) + int(full[d])
+                    dst_p = int(pos0[b]) + d
+                    self.caches = self._row_copy(
+                        self.caches, blks[src_p // bs], src_p % bs,
+                        blks[dst_p // bs], dst_p % bs)
+                for tok in emitted:
+                    task.output.append(tok)
+                    fresh.append((task, len(task.output) - 1))
+                emitted_total += m
+                pos_new = int(pos0[b]) + m
+                self.tokens[b] = emitted[-1]
+                self._tok_dev = None    # host token write: chain invalid
+                self.pos[b] = pos_new
+                task.decode_ms += dt * 1e3
+                live_tokens += pos_new
+                # rollback: free blocks holding only rejected-node KV
+                keep = self.allocator.blocks_for(pos_new)
+                if len(self._slot_blocks[b]) > keep:
+                    extra = self._slot_blocks[b][keep:]
+                    del self._slot_blocks[b][keep:]
+                    self.allocator.free(extra)
+                    self.block_tables[b, keep:] = -1
+                    self._tables_dev = None
+                # draft rewind: the dense draft cache followed the CHAIN, so
+                # it stays valid through the accepted path's leading chain
+                # prefix only (a sibling acceptance diverges from what the
+                # draft fed itself)
+                j_chain = 0
+                for i in path:
+                    if not tree.chain[i]:
+                        break
+                    j_chain += 1
+                self.draft_states[b].pos = min(int(starts[b]) + n_steps,
+                                               int(pos0[b]) + j_chain + 1,
+                                               pos_new)
+            self._round_depth = self._round_width = None
+            stats.decode_steps += 1
+            stats.spec_rounds += 1
+            stats.spec_slot_steps += occupied
+            stats.spec_emitted_tokens += emitted_total
+            stats.ar_tokens += emitted_total
+            stats.ar_time_s += dt
+            stats.add_decode_step_ms(dt * 1e3)
+            stats.occupied_slot_steps += occupied
+            stats.block_slot_steps += self.allocator.num_used
+            stats.token_slot_steps += live_tokens
+            executed = int(chunk_len.sum())
+            stats.verify_positions += executed
+            sp.set(slots=occupied,
+                   **round_annotation(proposed=round_proposed,
+                                      accepted=round_accepted,
+                                      emitted=emitted_total,
+                                      tree_nodes=round_nodes,
+                                      path_depths=path_depths,
+                                      branch_hits=round_branch))
         return fresh
 
     # -- execution: encoder-only NAR -----------------------------------
@@ -1413,21 +1387,24 @@ class ModelRunner:
         lens = [t.prompt_len for t in group]
         bucket = self.encode_bucket_for(max(lens))
         step = self._encode_for(bucket, n, group[0].pooling, stats)
-        t0 = time.perf_counter()
-        padded = np.zeros((n, bucket), np.int32)
-        for j, task in enumerate(group):
-            padded[j, :task.prompt_len] = np.asarray(task.prompt, np.int32)
-        batch = {"tokens": jnp.array(padded)}
-        if self.cfg.n_patches:
-            batch["patches"] = jnp.zeros(
-                (n, self.cfg.n_patches, self.cfg.d_model), jnp.bfloat16)
-        if self.cfg.enc_schedule:
-            batch["frames"] = jnp.zeros(
-                (n, self.cfg.enc_seq_padded, self.cfg.d_model), jnp.bfloat16)
-        pooled = step.fn(self.params, batch, jnp.array(lens, jnp.int32))
-        pooled_np = np.asarray(pooled)            # blocks: honest timing
-        now = time.perf_counter()
-        dt = now - t0
+        with trace.span(trace.ENCODE, self.tracer, bucket=bucket, group=n,
+                        uids=[t.uid for t in group]) as sp:
+            padded = np.zeros((n, bucket), np.int32)
+            for j, task in enumerate(group):
+                padded[j, :task.prompt_len] = np.asarray(task.prompt,
+                                                         np.int32)
+            batch = {"tokens": jnp.array(padded)}
+            if self.cfg.n_patches:
+                batch["patches"] = jnp.zeros(
+                    (n, self.cfg.n_patches, self.cfg.d_model), jnp.bfloat16)
+            if self.cfg.enc_schedule:
+                batch["frames"] = jnp.zeros(
+                    (n, self.cfg.enc_seq_padded, self.cfg.d_model),
+                    jnp.bfloat16)
+            pooled = step.fn(self.params, batch, jnp.array(lens, jnp.int32))
+            (pooled_np,), fetch = self._fetch(trace.ENCODE_FETCH, pooled)
+        now = fetch.t1
+        dt = now - sp.t0
         for j, task in enumerate(group):
             task.bucket = bucket
             task.embedding = pooled_np[j]
@@ -1440,8 +1417,3 @@ class ModelRunner:
             stats.bucket_hits[bucket] = stats.bucket_hits.get(bucket, 0) + 1
         stats.encode_time_s += dt
         stats.encode_batches += 1
-        if self.tracer:
-            self.tracer.step_span(
-                "encode", t0, now, phase="encode", bucket=bucket, group=n,
-                tokens=bucket * n, kv_positions=0, passes=1,
-                busy_ms=dt * 1e3, uids=[t.uid for t in group])

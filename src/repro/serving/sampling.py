@@ -88,11 +88,18 @@ def set_lane(lane: dict, slot: int, params: SamplingParams) -> dict:
     return out
 
 
+def upload(x: np.ndarray):
+    """A device array of host array `x` as it is now.  `jnp.array` of a
+    numpy array takes no host copy: the transfer may read `x` after the
+    call returns (CPU backend, async dispatch, a busy device), so an
+    in-place write that follows can reach a step already dispatched.
+    The copy made here is written by nobody."""
+    return jnp.asarray(x.copy())
+
+
 def device_lane(lane: dict) -> dict:
-    """Host lane -> device arrays for a jitted step call.  Copies: on the
-    CPU backend `jnp.asarray` may alias the numpy buffer, which the host
-    mutates while a dispatched step may not have read it yet."""
-    return {k: jnp.array(v) for k, v in lane.items()}
+    """Host lane -> device arrays for a jitted step call (`upload`)."""
+    return {k: upload(v) for k, v in lane.items()}
 
 
 def stack_lanes(params_list) -> dict:

@@ -164,12 +164,12 @@ class EngineStats:
     # percentiles come from this window
     ttft_slo_ratio: List[float] = field(default_factory=Reservoir)
     tpot_ms_samples: List[float] = field(default_factory=Reservoir)
-    # -- utilization attribution (serving/trace.py, analysis/roofline.py) ----
-    # per-token constants the engine stamps at construction so phase_util()
-    # can turn phase (time, token) sums into achieved MFU / MBU
-    device_kind: str = ""               # jax device_kind the engine runs on
-    model_flops_per_token: float = 0.0  # analytic fwd FLOPs per position
-    kv_bytes_per_token: float = 0.0     # KV bytes read per attended position
+    # -- host loop (serving/trace.py stage spans) ---------------------------
+    engine_steps: int = 0          # InferenceEngine.step() calls
+    host_s: float = 0.0            # seconds inside those steps outside the
+    #                                "*.fetch" spans: the host's part of
+    #                                each step, in which the synchronous
+    #                                loop has nothing queued on the chip
     # -- async overlapped host loop (engine overlap=True) --------------------
     overlapped_steps: int = 0      # decode steps whose token fetch was
     #                                deferred past host scheduling work
@@ -417,66 +417,10 @@ class EngineStats:
         return (self.block_slot_steps * self.kv_block_size
                 / self.token_slot_steps)
 
-    def phase_util(self, peaks=None) -> Dict[str, Dict[str, float]]:
-        """Per-phase achieved MFU / MBU from the engine's own counters.
-
-        Joins each phase's (busy time, token positions, weight passes, KV
-        positions attended) with the per-token FLOP / byte constants the
-        engine stamps at construction (analysis/roofline.py): useful
-        FLOPs = flops_per_token * positions; HBM traffic = weight bytes *
-        passes + KV bytes per token * attended positions.  Phases mirror
-        the paper's NAR / AR split: "prefill" (whole-prompt + chunked +
-        recompute passes), then "verify" under speculation or "decode"
-        otherwise (an engine runs one AR mode per session), plus "encode"
-        for encoder-only traffic.  {} when the FLOP constant is unknown
-        (stats not built by an engine).
-
-        `peaks` (analysis/roofline.DevicePeaks) defaults to the peak-table
-        entry of the engine's `device_kind`; on a device the table does
-        not list, "mfu"/"mbu" are None ("not measured") and the counts
-        stay."""
-        if not self.model_flops_per_token:
-            return {}
-        from repro.analysis.roofline import PEAKS, utilization
-        if peaks is None:
-            peaks = PEAKS.get(self.device_kind)
-        wbytes = float(self.weight_bytes_per_device)
-
-        def row(time_s, tokens, passes, kv_positions):
-            flops = self.model_flops_per_token * tokens
-            hbm = wbytes * passes + self.kv_bytes_per_token * kv_positions
-            mfu, mbu = utilization(flops, hbm, time_s, peaks)
-            return {"time_s": time_s, "tokens": float(tokens),
-                    "passes": float(passes),
-                    "kv_positions": float(kv_positions),
-                    "flops": flops, "hbm_bytes": hbm,
-                    "mfu": mfu, "mbu": mbu}
-
-        out: Dict[str, Dict[str, float]] = {}
-        pre_t = self.nar_time_s + self.recompute_time_s
-        if pre_t > 0:
-            out["prefill"] = row(
-                pre_t, self.padded_nar_tokens + self.recompute_tokens,
-                self.prefill_batches + self.prefill_chunks,
-                self.nar_tokens + self.recompute_tokens)
-        if self.ar_time_s > 0:
-            if self.spec_rounds:
-                out["verify"] = row(self.ar_time_s, self.verify_positions,
-                                    self.spec_rounds, self.token_slot_steps)
-            else:
-                out["decode"] = row(self.ar_time_s, self.ar_tokens,
-                                    self.decode_steps, self.token_slot_steps)
-        if self.encode_time_s > 0:
-            out["encode"] = row(self.encode_time_s,
-                                self.padded_encode_tokens,
-                                self.encode_batches, 0)
-        return out
-
     def to_dict(self) -> dict:
         """JSON-ready snapshot (benchmarks/serving_bench.py)."""
         return {
             "batch_size": self.batch_size,
-            "device_kind": self.device_kind,
             "requests_submitted": self.requests_submitted,
             "requests_completed": self.requests_completed,
             "nar_tokens": self.nar_tokens,
@@ -530,6 +474,8 @@ class EngineStats:
             "overlapped_steps": self.overlapped_steps,
             "overlap_host_s": self.overlap_host_s,
             "host_overlap_ratio": self.host_overlap_ratio,
+            "engine_steps": self.engine_steps,
+            "host_s": self.host_s,
             **{f"queue_wait_{k}_ms": v
                for k, v in percentiles(self.queue_wait_ms).items()},
             **{f"decode_step_{k}_ms": v
@@ -558,9 +504,6 @@ class EngineStats:
             "kv_dtype": self.kv_dtype,
             "weight_bytes_per_device": self.weight_bytes_per_device,
             "kv_pool_bytes": self.kv_pool_bytes,
-            "model_flops_per_token": self.model_flops_per_token,
-            "kv_bytes_per_token": self.kv_bytes_per_token,
-            "phase_util": self.phase_util(),
         }
 
     def summary(self) -> str:

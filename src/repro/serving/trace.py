@@ -1,42 +1,50 @@
-"""Request-level tracing + MFU/MBU attribution for the serving engine.
+"""Engine stage spans on the profiler's clock, and request-level tracing.
 
-The paper's headline results are *attribution* results — per-phase cycle
-breakdowns and FPU-utilization numbers that explain where every cycle
-goes.  `EngineStats` aggregates; this module answers the per-request and
-per-interval questions aggregates cannot: why was request #1743 slow
-(queued? shed? preempted? degraded?), and what was decode MFU during the
-bursty window?
+Two recorders share one set of hook sites in engine.py and runner.py:
 
-`Tracer` is a low-overhead structured tracer:
+  stage spans     `with span(name, tracer, **args):` around every stage of
+      an engine step.  Each opens a `jax.profiler.TraceAnnotation`, which
+      records only while a profiler session runs (`jax.profiler.trace`)
+      and then sits on the same clock as the device's ops; otherwise it
+      costs about a microsecond.  The span also keeps its own
+      `perf_counter` readings, from which the engine feeds
+      `EngineStats.engine_steps` and `EngineStats.host_s` (the seconds
+      of each step spent outside the `*.fetch` spans).  Names are the
+      constants below, all under `engine.`, nested as the work nests:
 
-  request rows (pid 2, tid = uid)   lifecycle spans and instants —
-      submit -> queue -> (warm_hit | degrade | preempt | shed) ->
-      first_token -> retire ("request" span).  Policy decisions annotate
-      the spans: EDF slack at admission, shed reason, degrade rung, COW
-      copies, cached-prefix length, tree accept depth.
-  engine row (pid 1, tid 0)         per-step spans — prefill / chunk /
-      decode (dispatch vs commit split, overlap lag, batch composition) /
-      draft / verify / encode, plus one "engine_step" wrapper per engine
-      iteration.
+        engine.step                     one InferenceEngine.step()
+          engine.encode / .fetch        an encode batch / its host copy
+          engine.admit                  admission and retirement loop
+            engine.prefill / .fetch     a prefill group / its token fetch
+            engine.chunk / .fetch       a warm-suffix or chunked piece
+            engine.retire               release of finished slots
+          engine.blocks                 decode blocks: allocation, COW,
+                                        preemption
+          engine.decode.dispatch        input uploads and the program call
+          engine.decode.fetch           the blocking token fetch
+          engine.decode.commit          per-slot bookkeeping
+          engine.spec.draft / .fetch    speculation's draft proposals
+          engine.spec.verify / .fetch   its verify pass and commit
 
-Events land in a bounded ring buffer (deque, drop-oldest) and export as
-Chrome trace-event JSON (`chrome_trace` / `write`) viewable at
-https://ui.perfetto.dev, or as a flat Prometheus-style text snapshot of
-an `EngineStats.to_dict()` (`prometheus_text`).
+      `engine.decode.dispatch` carries `kv_positions`, the KV positions
+      the step's attention reads summed over its decoding slots, so that
+      a profiler trace holds the decode attention's work beside its
+      kernel time.  Prefill, chunk and decode spans carry `uids`.
 
-Tracing is OPT-IN with a no-op fast path: every hook site in
-engine.py/runner.py guards on a single `if tracer:` branch (`__bool__` is
-the enabled flag), so a disabled or absent tracer costs one falsy check
-per hook and records nothing — token outputs are identical either way
-(hooks are pure observers).
+  ring Tracer     (opt-in, `serve.py --trace-out`) a bounded ring buffer
+      exported as Chrome trace-event JSON for https://ui.perfetto.dev:
+      with an enabled Tracer every stage span is also pushed to the
+      engine row (pid 1), and the request rows (pid 2, tid = uid) hold
+      each request's lifecycle — submit -> queue -> (warm_hit | degrade
+      | preempt | shed) -> first_token -> retire ("request" span) — with
+      the scheduler's annotations.  Request-row hooks sit behind one
+      `if tracer:` branch.
 
-`derive_phase_metrics` joins the step spans with the analysis/roofline.py
-FLOP/byte model to report achieved MFU/MBU per serving phase (prefill vs
-decode vs verify); `EngineStats.phase_util()` computes the same
-attribution from counters alone (no tracer needed), and the two agree on
-traced runs (tests/test_trace.py).
+Both are pure observers: tokens are identical with or without a profiler
+session or a Tracer.  `prometheus_text` renders an `EngineStats.to_dict()`
+snapshot in Prometheus text format.
 
-CLI validator (the CI artifact gate):
+CLI validator for a Tracer artifact:
 
     PYTHONPATH=src python -m repro.serving.trace TRACE.json
 """
@@ -45,11 +53,75 @@ from __future__ import annotations
 import json
 import time
 from collections import deque
-from typing import Dict, Iterable, List, Optional
+from typing import Dict, List
+
+from jax.profiler import TraceAnnotation
 
 # Chrome trace-event process rows: one for engine steps, one per-request.
 PID_ENGINE = 1
 PID_REQUEST = 2
+
+# Stage span names (module docstring).  A name ending in FETCH marks time
+# the host spends blocked on the chip's results.
+FETCH = ".fetch"
+ENGINE_STEP = "engine.step"
+ADMIT = "engine.admit"
+RETIRE = "engine.retire"
+PREFILL = "engine.prefill"
+PREFILL_FETCH = PREFILL + FETCH
+CHUNK = "engine.chunk"
+CHUNK_FETCH = CHUNK + FETCH
+BLOCKS = "engine.blocks"
+DECODE_DISPATCH = "engine.decode.dispatch"
+DECODE_FETCH = "engine.decode" + FETCH
+DECODE_COMMIT = "engine.decode.commit"
+SPEC_DRAFT = "engine.spec.draft"
+SPEC_DRAFT_FETCH = SPEC_DRAFT + FETCH
+SPEC_VERIFY = "engine.spec.verify"
+SPEC_VERIFY_FETCH = SPEC_VERIFY + FETCH
+ENCODE = "engine.encode"
+ENCODE_FETCH = ENCODE + FETCH
+
+
+class Span:
+    """One stage of an engine step (see `span`).  `t0`/`t1` are its
+    `perf_counter` readings; `set` adds args known only once the stage's
+    work is done."""
+
+    __slots__ = ("name", "tracer", "args", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, tracer, args: dict):
+        self.name = name
+        self.tracer = tracer
+        self.args = args
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "Span":
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def set(self, **args):
+        self._ann.set_metadata(**args)
+        self.args.update(args)
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self.tracer:
+            self.tracer.step_span(self.name, self.t0, self.t1, **self.args)
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
+def span(name: str, tracer=None, **args) -> Span:
+    """A stage span: a profiler annotation named `name` with `args`, and,
+    with an enabled ring `tracer`, the same event on its engine row."""
+    return Span(name, tracer, args)
 
 
 class Tracer:
@@ -72,8 +144,8 @@ class Tracer:
         self.dropped = 0
 
     def __bool__(self) -> bool:
-        # THE no-op fast path: every hook site is `if tracer: ...`, so a
-        # disabled tracer (or None) costs one falsy check and nothing else
+        # a disabled tracer (or None) records nothing: request-row hooks
+        # are `if tracer: ...`, and a stage span pushes only `if tracer`
         return self.enabled
 
     @property
@@ -104,8 +176,7 @@ class Tracer:
                     "pid": pid, "tid": tid, "args": args})
 
     def step_span(self, name: str, t0: float, t1: float, **args):
-        """Engine-row span (pid 1, tid 0): prefill / decode / verify /
-        encode passes and the per-iteration engine_step wrapper."""
+        """Engine-row span (pid 1, tid 0): one stage span."""
         self.span(name, t0, t1, pid=PID_ENGINE, tid=0, cat="step", **args)
 
     def request_span(self, uid: int, name: str, t0: float, t1: float,
@@ -203,61 +274,12 @@ def validate_chrome_trace(doc: dict) -> List[str]:
     return problems
 
 
-# -- interval metrics: join step spans with the roofline FLOP/byte model -
-def derive_phase_metrics(events: Iterable[dict], *, flops_per_token: float,
-                         weight_bytes: float, kv_bytes_per_token: float,
-                         peaks=None) -> Dict[str, dict]:
-    """Per-phase achieved MFU/MBU from recorded step spans.
-
-    Every compute span (prefill / prefill_chunk / decode_step /
-    spec_verify / encode) carries `phase`, `tokens` (positions the pass
-    executed, padding included), `kv_positions` (live KV positions the
-    pass read/wrote), `passes`, and `busy_ms` (device-busy wall, floored
-    against pipelined neighbors so overlapped steps never double-count).
-
-      MFU = flops_per_token * tokens / (busy_s * peak)      [achieved/peak]
-      MBU = (weight_bytes * passes + kv_bytes_per_token * kv_positions)
-            / (busy_s * bw)
-
-    `flops_per_token` is the analytic decoder forward cost
-    (analysis/roofline.decoder_flops_per_token); `peaks` is the recording
-    device's analysis/roofline.DevicePeaks — None (a device the peak
-    table does not list) reports MFU/MBU as None, "not measured", and
-    keeps the counts.  The "draft" phase reports time only (its FLOPs
-    belong to a different, smaller model)."""
-    from repro.analysis.roofline import utilization
-    acc: Dict[str, dict] = {}
-    for e in events:
-        args = e.get("args") or {}
-        phase = args.get("phase")
-        if e.get("cat") != "step" or phase is None:
-            continue
-        a = acc.setdefault(phase, {"time_s": 0.0, "tokens": 0,
-                                   "kv_positions": 0, "passes": 0})
-        busy = args.get("busy_ms")
-        a["time_s"] += (busy / 1e3 if busy is not None
-                        else e.get("dur", 0.0) / 1e6)
-        a["tokens"] += int(args.get("tokens", 0))
-        a["kv_positions"] += int(args.get("kv_positions", 0))
-        a["passes"] += int(args.get("passes", 0))
-    out: Dict[str, dict] = {}
-    for phase, a in acc.items():
-        flops = flops_per_token * a["tokens"]
-        mem = weight_bytes * a["passes"] + kv_bytes_per_token * a[
-            "kv_positions"]
-        mfu, mbu = utilization(flops, mem, a["time_s"], peaks)
-        out[phase] = {**a, "flops": flops, "hbm_bytes": mem,
-                      "mfu": mfu, "mbu": mbu}
-    return out
-
-
 # -- flat Prometheus-style text snapshot --------------------------------
 def prometheus_text(snapshot: dict, prefix: str = "serving") -> str:
     """Flatten an `EngineStats.to_dict()` into Prometheus text exposition
     format: scalars become `<prefix>_<key> <value>`, one-level dicts
-    become labeled series (`bucket_hits` -> {bucket="8"}, `phase_util`
-    -> per-phase {phase="decode"} series), and string fields collapse
-    into one `<prefix>_info{...} 1` metric."""
+    become labeled series (`bucket_hits` -> {bucket="8"}), and string
+    fields collapse into one `<prefix>_info{...} 1` metric."""
     lines: List[str] = []
     info: Dict[str, str] = {}
     for key, val in snapshot.items():
@@ -268,19 +290,10 @@ def prometheus_text(snapshot: dict, prefix: str = "serving") -> str:
         elif isinstance(val, str):
             info[key] = val
         elif isinstance(val, dict):
-            if key == "phase_util":
-                for phase, m in val.items():
-                    for mk, mv in m.items():
-                        if isinstance(mv, (int, float)):
-                            lines.append(
-                                f'{prefix}_phase_{mk}{{phase="{phase}"}} '
-                                f"{mv:g}")
-            else:
-                label = key.rstrip("s") or key
-                for k, v in val.items():
-                    if isinstance(v, (int, float)):
-                        lines.append(
-                            f'{prefix}_{key}{{{label}="{k}"}} {v:g}')
+            label = key.rstrip("s") or key
+            for k, v in val.items():
+                if isinstance(v, (int, float)):
+                    lines.append(f'{prefix}_{key}{{{label}="{k}"}} {v:g}')
     if info:
         labels = ",".join(f'{k}="{v}"' for k, v in sorted(info.items()))
         lines.append(f"{prefix}_info{{{labels}}} 1")
@@ -314,8 +327,8 @@ def main(argv=None) -> int:
     return 0
 
 
-__all__ = ["Tracer", "PID_ENGINE", "PID_REQUEST", "validate_chrome_trace",
-           "derive_phase_metrics", "prometheus_text"]
+__all__ = ["Tracer", "Span", "span", "PID_ENGINE", "PID_REQUEST",
+           "validate_chrome_trace", "prometheus_text"]
 
 if __name__ == "__main__":
     import sys

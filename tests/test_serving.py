@@ -311,6 +311,24 @@ def test_prefill_logits_argmax_is_greedy_token():
     np.testing.assert_array_equal(np.asarray(z).argmax(-1), np.asarray(tok))
 
 
+def test_upload_snapshots_the_host_array_behind_a_busy_device():
+    """`jnp.array(numpy)` takes no host copy: with the CPU device busy the
+    transfer can run after the call returns and read a write made after
+    it.  `upload` (tokens, positions, block tables, lanes) must hand the
+    step the values as of the call."""
+    from repro.serving.sampling import upload
+    busy = jax.jit(lambda m: (m @ m) @ m)
+    m = jnp.ones((1000, 1000), jnp.float32)
+    busy(m).block_until_ready()
+    for _ in range(20):
+        pending = busy(m)
+        host = np.zeros((2,), np.int32)
+        dev = upload(host)
+        host += 7
+        assert int(np.asarray(dev).max()) == 0
+    pending.block_until_ready()
+
+
 def test_device_lane_uploads_a_copy():
     """The runner mutates its host lanes in place after dispatching a step;
     the device arrays handed to that step must not alias them (on the CPU

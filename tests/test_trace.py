@@ -1,30 +1,35 @@
-"""Observability layer: request-level tracing + MFU/MBU attribution.
+"""Observability layer: stage spans on the profiler's clock and
+request-level tracing.
 
-Covers serving/trace.py end to end through the engine: span nesting and
-ordering under the overlapped host loop, ring-buffer eviction, Chrome
-trace-event schema validity (Perfetto-loadable), token identity with
-tracing on vs off across chunked / prefix-warm / speculative traffic,
-TTFT reconstruction from the trace alone, the per-phase MFU/MBU
-derivation (stats.phase_util vs trace.derive_phase_metrics agreeing),
+Covers serving/trace.py end to end through the engine: the stage spans'
+nesting in one synchronous step, their capture by a real jax.profiler
+session (with the decode step's `kv_positions`), token identity with a
+profiler session or a ring Tracer on vs off, the `engine_steps` /
+`host_s` counters the spans feed, span nesting and ordering under the
+overlapped host loop, ring-buffer eviction, Chrome trace-event schema
+validity (Perfetto-loadable), TTFT reconstruction from the trace alone,
 the Reservoir sampling satellite, and the Prometheus text snapshot.
 """
+import glob
+import gzip
 import json
+import os
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.analysis.roofline import PEAKS
 from repro.configs import get_config
 from repro.core.precision import FP32
 from repro.models import lm
 from repro.serving import (ChunkedPrefillPolicy, DeadlinePolicy,
                            InferenceEngine, Request, Reservoir,
-                           SamplingParams, SpecConfig, Tracer,
-                           derive_phase_metrics, make_policy, percentile,
-                           prometheus_text, spec_support_reason,
+                           SamplingParams, SpecConfig, Tracer, make_policy,
+                           percentile, prometheus_text, spec_support_reason,
                            validate_chrome_trace)
+from repro.serving import trace
 from repro.serving.stats import EngineStats
 from repro.serving.trace import PID_ENGINE, PID_REQUEST
 
@@ -132,9 +137,9 @@ def test_span_ordering_and_nesting(traced_run):
     # engine rows exist too: step spans on the engine pid
     steps = [e for e in doc["traceEvents"] if e.get("cat") == "step"]
     assert steps and all(e["pid"] == PID_ENGINE for e in steps)
-    assert {"prefill", "decode_step", "engine_step"} <= {
+    assert {trace.PREFILL, trace.DECODE_FETCH, trace.ENGINE_STEP} <= {
         e["name"] for e in steps}
-    assert any(e["name"] == "decode_dispatch" for e in steps)
+    assert any(e["name"] == trace.DECODE_DISPATCH for e in steps)
 
 
 def test_ttft_reconstruction(traced_run):
@@ -182,7 +187,7 @@ def test_token_identity_traced_vs_untraced(model, mode):
 
 def test_warm_prefix_emits_warm_hit(model):
     """Second pass over a shared prefix emits warm_hit instants and the
-    prefill_chunk/prefill spans mark recompute vs first admission."""
+    engine.chunk spans mark recompute vs first admission."""
     cfg, params = model
     rng = np.random.default_rng(4)
     shared = rng.integers(0, cfg.vocab, 24, dtype=np.int32)
@@ -211,83 +216,145 @@ def test_spec_trace_annotations(model):
         pytest.skip(spec_support_reason(cfg))
     tracer = Tracer()
     _run(cfg, params, tracer=tracer, spec=SpecConfig(draft="self", k=2))
-    verifies = [e for e in tracer.events if e["name"] == "spec_verify"]
-    drafts = [e for e in tracer.events if e["name"] == "spec_draft"]
+    verifies = [e for e in tracer.events if e["name"] == trace.SPEC_VERIFY]
+    drafts = [e for e in tracer.events if e["name"] == trace.SPEC_DRAFT]
     assert verifies and drafts
     for v in verifies:
         a = v["args"]
-        assert a["phase"] == "verify"
+        assert a["slots"] >= 1
         assert a["proposed"] >= a["accepted"] >= 0
         assert 0.0 <= a["accept_rate"] <= 1.0
 
 
-def test_phase_util_and_trace_derivation_agree(traced_run):
-    """stats.phase_util() (the counters) and derive_phase_metrics (the
-    trace) are two routes to the same per-phase numbers.  The CPU is not
-    in the peak table, so both keep the counts and report MFU/MBU as
-    None ("not measured")."""
-    _, engine, _ = traced_run
-    tracer = engine.tracer
-    st = engine.stats()
-    assert st.device_kind == jax.devices()[0].device_kind
-    assert PEAKS.get(st.device_kind) is None
-    pu = st.phase_util()
-    assert "prefill" in pu and "decode" in pu
-    for row in pu.values():
-        assert row["time_s"] > 0 and row["tokens"] > 0
-        assert row["flops"] > 0 and row["hbm_bytes"] > 0
-        assert row["mfu"] is None and row["mbu"] is None
-    derived = derive_phase_metrics(
-        tracer.events,
-        flops_per_token=st.model_flops_per_token,
-        weight_bytes=st.weight_bytes_per_device,
-        kv_bytes_per_token=st.kv_bytes_per_token,
-        peaks=PEAKS.get(st.device_kind))
-    for phase in ("prefill", "decode"):
-        assert phase in derived
-        for key in ("time_s", "tokens", "flops", "hbm_bytes"):
-            assert derived[phase][key] == pytest.approx(
-                pu[phase][key], rel=1e-6), (phase, key)
-        assert derived[phase]["mfu"] is None
-        assert derived[phase]["mbu"] is None
-    d = st.to_dict()
-    assert d["phase_util"] == pu
-    assert d["model_flops_per_token"] > 0
-    assert d["kv_bytes_per_token"] > 0
+def _record_annotations(monkeypatch):
+    """Stand in for jax.profiler.TraceAnnotation: -> the (depth, name,
+    args) of every annotation, in the order they open."""
+    log = []
+    depth = [0]
+
+    class Recorder:
+        def __init__(self, name, **args):
+            self.args = dict(args)
+            log.append((depth[0], name, self.args))
+
+        def __enter__(self):
+            depth[0] += 1
+            return self
+
+        def set_metadata(self, **args):
+            self.args.update(args)
+
+        def __exit__(self, *exc):
+            depth[0] -= 1
+
+    monkeypatch.setattr(trace, "TraceAnnotation", Recorder)
+    return log
 
 
-def test_phase_util_with_explicit_v5e_peaks(traced_run):
-    """Given a device's peaks explicitly, both routes report the same
-    positive MFU/MBU: achieved rate over the table's bf16 / HBM peak."""
-    _, engine, _ = traced_run
-    st = engine.stats()
-    v5e = PEAKS["TPU v5 lite"]
-    pu = st.phase_util(peaks=v5e)
-    derived = derive_phase_metrics(
-        engine.tracer.events,
-        flops_per_token=st.model_flops_per_token,
-        weight_bytes=st.weight_bytes_per_device,
-        kv_bytes_per_token=st.kv_bytes_per_token, peaks=v5e)
-    for phase in ("prefill", "decode"):
-        row = pu[phase]
-        assert row["mfu"] == pytest.approx(
-            row["flops"] / (row["time_s"] * v5e.bf16_flops), rel=1e-9)
-        assert row["mbu"] == pytest.approx(
-            row["hbm_bytes"] / (row["time_s"] * v5e.hbm_bw), rel=1e-9)
-        assert row["mfu"] > 0 and row["mbu"] > 0
-        for key in ("mfu", "mbu"):
-            assert derived[phase][key] == pytest.approx(row[key],
-                                                        rel=1e-6)
-
-
-def test_spec_engine_attributes_verify_phase(model):
+def test_stage_spans_nest_in_one_sync_step(model, monkeypatch):
+    """One synchronous step that admits a request and decodes it opens
+    the stage spans in the order, and at the depth, the work nests."""
     cfg, params = model
-    if spec_support_reason(cfg) is not None:
-        pytest.skip(spec_support_reason(cfg))
-    engine, _ = _run(cfg, params, spec=SpecConfig(draft="self", k=2))
-    pu = engine.stats().phase_util(peaks=PEAKS["TPU v5 lite"])
-    assert "verify" in pu and "decode" not in pu
-    assert pu["verify"]["mfu"] > 0
+    engine = InferenceEngine(cfg, params, batch_size=2, max_seq=64,
+                             policy=FP32)
+    prompt = _prompts(cfg, (6,))[0]
+    engine.submit(Request(uid=7, prompt=prompt, max_new_tokens=4))
+    log = _record_annotations(monkeypatch)
+    engine.step()
+    assert [(d, n) for d, n, _ in log] == [
+        (0, trace.ENGINE_STEP),
+        (1, trace.ADMIT),
+        (2, trace.PREFILL), (3, trace.PREFILL_FETCH),
+        (2, trace.RETIRE),
+        (1, trace.RETIRE),
+        (1, trace.BLOCKS),
+        (1, trace.DECODE_DISPATCH), (1, trace.DECODE_FETCH),
+        (1, trace.DECODE_COMMIT),
+        (1, trace.RETIRE)]
+    args = {n: a for _, n, a in log}
+    assert args[trace.ENGINE_STEP] == {"n": 0, "queued": 1, "running": 0}
+    assert args[trace.ADMIT] == {"admitted": 1}
+    assert args[trace.PREFILL]["tokens"] == 6
+    assert args[trace.PREFILL]["uids"] == [7]
+    # the decode input sits at position 6 and attends positions 0..6
+    assert args[trace.DECODE_DISPATCH]["kv_positions"] == 7
+    assert args[trace.DECODE_DISPATCH]["uids"] == [7]
+    log.clear()
+    engine.step()
+    assert log[0] == (0, trace.ENGINE_STEP,
+                      {"n": 1, "queued": 0, "running": 1})
+    assert [n for _, n, _ in log if n == trace.PREFILL] == []
+
+
+@pytest.fixture(scope="module")
+def profiled_run(model, tmp_path_factory):
+    """The tiny engine served inside a real jax.profiler session: its
+    tokens, stats, and the host events of the exported trace."""
+    cfg, params = model
+    out = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(out, profiler_options=opts)
+    try:
+        engine, tokens = _run(cfg, params)
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                   "*.trace.json.gz"))
+    assert len(files) == 1, files
+    with gzip.open(files[0], "rt") as f:
+        events = [e for e in json.load(f)["traceEvents"]
+                  if e.get("ph") == "X"
+                  and str(e.get("name", "")).startswith("engine.")]
+    return tokens, engine.stats(), events
+
+
+def test_engine_spans_in_profiler_capture(profiled_run):
+    """The stage spans land in the profiler's own trace file, with their
+    args: one engine.step per step, and the decode steps' kv_positions
+    summing to the KV positions the steps attended."""
+    _, st, events = profiled_run
+    names = {e["name"] for e in events}
+    assert {trace.ENGINE_STEP, trace.ADMIT, trace.PREFILL,
+            trace.PREFILL_FETCH, trace.DECODE_DISPATCH, trace.DECODE_FETCH,
+            trace.DECODE_COMMIT, trace.RETIRE, trace.BLOCKS} <= names
+    assert sum(e["name"] == trace.ENGINE_STEP
+               for e in events) == st.engine_steps
+    dispatch = [e for e in events if e["name"] == trace.DECODE_DISPATCH]
+    assert len(dispatch) == st.decode_steps
+    kv = [int(e["args"]["kv_positions"]) for e in dispatch]
+    assert all(k > 0 for k in kv)
+    assert sum(kv) == st.token_slot_steps
+
+
+def test_tokens_identical_with_profiler_on_and_off(model, profiled_run):
+    tokens, _, _ = profiled_run
+    cfg, params = model
+    _, plain = _run(cfg, params)
+    assert tokens == plain
+
+
+def test_host_s_and_engine_steps(model):
+    """engine_steps counts step() calls; host_s is the part of their wall
+    time spent outside the token fetches: positive, below the total."""
+    cfg, params = model
+    engine = InferenceEngine(cfg, params, batch_size=2, max_seq=64,
+                             policy=FP32)
+    for i, p in enumerate(_prompts(cfg, (6, 13, 20))):
+        engine.submit(Request(uid=i, prompt=p, max_new_tokens=4))
+    walls = []
+    while engine.has_work():
+        t0 = time.perf_counter()
+        engine.step()
+        walls.append(time.perf_counter() - t0)
+    st = engine.stats()
+    assert st.engine_steps == len(walls)
+    assert 0 < st.host_s < sum(walls)
+    d = st.to_dict()
+    assert d["engine_steps"] == len(walls) and d["host_s"] == st.host_s
+    engine.reset_stats()
+    assert engine.stats().engine_steps == 0
+    assert engine.stats().host_s == 0.0
 
 
 def test_reservoir_keeps_late_outliers():
@@ -327,9 +394,7 @@ def test_prometheus_text_snapshot(traced_run):
     _, engine, _ = traced_run
     text = prometheus_text(engine.stats().to_dict())
     assert "serving_ar_tok_s" in text
-    assert 'serving_phase_flops{phase="decode"}' in text
-    # MFU on a device without peaks is "not measured": never exported
-    assert "serving_phase_mfu" not in text
+    assert "serving_engine_steps" in text and "serving_host_s" in text
     for line in text.splitlines():
         if line and not line.startswith("#"):
             name, val = line.rsplit(" ", 1)
