@@ -446,40 +446,39 @@ SCALE_EPS = 1e-30      # guards zero-amax blocks and unwritten scale slots
 
 def _quantized_kv(cache) -> bool:
     """True when the paged pools store int8 K/V with per-block-per-head
-    scales ({"ks","vs"} [NB_loc, KV] fp32 leaves alongside {"k","v"})."""
+    scales ({"ks","vs"} [.., NB_loc, KV] fp32 leaves alongside
+    {"k","v"})."""
     return "ks" in cache
 
 
-def _append_quantized(pool, scales, x_new, loc, off):
+def _quantize_row(x_new, s_old, fresh):
     """Quantize-on-write for a single-token paged append.  x_new: [B, KV, hd]
-    fp-valued rows; loc [B] local block ids (out-of-range => dropped);
-    off [B] in-block offsets.  Blocks fill front-to-back, so a token landing
-    at offset 0 is the block's first (re)use: it (re)sets the block scale
-    from its own amax.  Later offsets reuse the stored scale and clip —
-    entries already in the block never move, which is what makes speculative
-    rollback (a fill-count rewind) and COW sharing safe."""
-    NB_loc = pool.shape[0]
+    fp-valued rows; s_old [B, KV] the stored scales of their blocks;
+    fresh [B] marks rows landing at offset 0.  Blocks fill front-to-back, so
+    a token at offset 0 is the block's first (re)use: it (re)sets the block
+    scale from its own amax.  Later offsets reuse the stored scale and
+    clip — entries already in the block never move, which is what makes
+    speculative rollback (a fill-count rewind) and COW sharing safe.
+    -> (q int8 [B, KV, hd], s_new [B, KV] the scale a fresh block takes,
+    s_read [B, KV] the scale the block holds once the row is written)."""
     xf = x_new.astype(jnp.float32)
     amax = jnp.max(jnp.abs(xf), axis=-1)                       # [B, KV]
     s_new = jnp.maximum(amax, SCALE_EPS) / 127.0
-    fresh = off == 0
-    s_old = scales[jnp.clip(loc, 0, NB_loc - 1)]               # [B, KV]
-    s = jnp.where(fresh[:, None], s_new, jnp.maximum(s_old, SCALE_EPS))
+    fresh = fresh[:, None]
+    s = jnp.where(fresh, s_new, jnp.maximum(s_old, SCALE_EPS))
     q = jnp.clip(jnp.round(xf / s[..., None]), -127, 127).astype(jnp.int8)
-    sloc = jnp.where(fresh, loc, NB_loc)     # only fresh blocks write scale
-    return (pool.at[loc, off].set(q, mode="drop"),
-            scales.at[sloc].set(s_new, mode="drop"))
+    return q, s_new, jnp.where(fresh, s_new, s_old)
 
 
 def _scatter_quantized(pool, scales, x_new, loc, off, fresh):
-    """Quantize-on-write for a multi-token chunk scatter.  x_new:
-    [B, C, KV, hd]; loc/off [B, C] (non-owned / pad tokens routed to
-    loc == NB_loc => dropped); `fresh` [B, C] marks tokens whose block's
-    offset 0 lies inside this write.  Fresh blocks take their scale from a
-    scatter-max over the chunk's own token amaxes (exactly the per-block
-    amax of what lands in them); stale blocks keep their stored scale and
-    this chunk's tokens clip against it — same invariant as
-    `_append_quantized`, vectorized."""
+    """Quantize-on-write for a multi-token chunk scatter into one layer's
+    pool [NB_loc, BS, KV*hd].  x_new: [B, C, KV, hd]; loc/off [B, C]
+    (non-owned / pad tokens routed to loc == NB_loc => dropped); `fresh`
+    [B, C] marks tokens whose block's offset 0 lies inside this write.
+    Fresh blocks take their scale from a scatter-max over the chunk's own
+    token amaxes (exactly the per-block amax of what lands in them); stale
+    blocks keep their stored scale and this chunk's tokens clip against it
+    — same invariant as `_quantize_row`, vectorized."""
     NB_loc = pool.shape[0]
     xf = x_new.astype(jnp.float32)
     amax = jnp.max(jnp.abs(xf), axis=-1)                       # [B, C, KV]
@@ -490,6 +489,7 @@ def _scatter_quantized(pool, scales, x_new, loc, off, fresh):
                                  mode="drop")
     s = jnp.maximum(scales[jnp.clip(loc, 0, NB_loc - 1)], SCALE_EPS)
     q = jnp.clip(jnp.round(xf / s[..., None]), -127, 127).astype(jnp.int8)
+    q = q.reshape(*q.shape[:2], -1)                            # [B, C, KV*hd]
     return pool.at[loc, off].set(q, mode="drop"), scales
 
 
@@ -501,8 +501,8 @@ def attn_chunk_paged(p, x, pos0, chunk_len, cache, block_tables, *,
     x: [B, C, E] — C consecutive prompt tokens per row, starting at absolute
     position `pos0` [B]; `chunk_len` [B] is the true token count this chunk
     carries (<= C; the tail is padding whose KV is never scattered and whose
-    outputs the caller discards); cache: {"k","v"} pool shards
-    [NB_loc, BS, KV, hd]; block_tables: [B, MB] global pool indices.
+    outputs the caller discards); cache: {"k","v"} one layer's pool shards
+    [NB_loc, BS, KV*hd]; block_tables: [B, MB] global pool indices.
 
     The chunk's KV rows are scattered into their blocks FIRST, then the
     chunk queries attend the pool (prefix + this chunk) under a per-query
@@ -555,9 +555,11 @@ def attn_chunk_paged(p, x, pos0, chunk_len, cache, block_tables, *,
     else:
         cache = {
             "k": cache["k"].at[loc, off].set(
-                k_new.astype(cache["k"].dtype), mode="drop"),
+                k_new.reshape(B, C, KV * hd).astype(cache["k"].dtype),
+                mode="drop"),
             "v": cache["v"].at[loc, off].set(
-                v_new.astype(cache["v"].dtype), mode="drop"),
+                v_new.reshape(B, C, KV * hd).astype(cache["v"].dtype),
+                mode="drop"),
         }
 
     # local table view (entries this shard owns, local ids)
@@ -578,64 +580,133 @@ def attn_chunk_paged(p, x, pos0, chunk_len, cache, block_tables, *,
     return y.reshape(B, C, E), cache
 
 
-def attn_decode_paged(p, x, pos, cache, block_tables, *, plan: Plan, cfg,
-                      policy: Policy, norm=None, residual=None):
-    """One decode step against a block-paged KV cache (full-context layers
-    only — window/ring layers keep the dense per-slot ring, `attn_decode`).
-
-    x: [B, E]; pos: [B] — position index of the token being written;
-    cache: {"k","v"} pool shards [NB_loc, BS, KV, hd], block-sharded over
-    `plan.cache_axes`; block_tables: [B, MB] int32 *global* pool indices in
-    sequence order (< 0 = unallocated).  Returns (y [B, E], updated cache).
-
-    The new token's KV lands in block table[pos // BS] at offset pos % BS —
-    a single per-block scatter.  Attention dispatches to the paged split-KV
-    partials kernel (kernels/ops.paged_decode_partials) over the blocks this
-    shard owns (absent / non-owned table entries masked), and the per-shard
-    online-softmax partials merge across cache shards with the same T4 rule
-    as the dense path — the pool is never gathered."""
-    c_ax = plan.cache_axes
-    B, E = x.shape
-    H, hd = cfg.n_heads, cfg.head_dim
-    ad = act_dtype(policy)
-
-    NB_loc, BS = cache["k"].shape[0], cache["k"].shape[1]
-    start = col.axis_index(c_ax) * NB_loc          # first owned global block
-
-    q = _decode_q(p, x, pos, plan=plan, cfg=cfg, policy=policy, norm=norm)
-    k_new, v_new = _decode_kv_new(p, x, pos, plan=plan, cfg=cfg,
-                                  policy=policy, norm=norm)
-
-    # scatter the new token into its block (absent / non-owned -> dropped;
-    # negative ids wrap in .at[], so route them out of bounds instead)
+def _decode_slot(pos, block_tables, NB_loc: int, BS: int, plan: Plan):
+    """Where this step's token goes in this shard's pool: -> (loc [B] local
+    block id, NB_loc where the block is absent or another shard's — an
+    out-of-range index that `mode="drop"` skips; negative ids would wrap
+    in .at[] —, off [B] in-block offset, owned [B] bool)."""
+    start = col.axis_index(plan.cache_axes) * NB_loc   # first owned block
     gb = jnp.take_along_axis(block_tables, (pos // BS)[:, None],
                              axis=1)[:, 0]                       # [B]
     loc = gb - start
     owned = (gb >= 0) & (loc >= 0) & (loc < NB_loc)
-    loc = jnp.where(owned, loc, NB_loc)
-    off = pos % BS
-    if _quantized_kv(cache):
-        kp, ks = _append_quantized(cache["k"], cache["ks"], k_new, loc, off)
-        vp, vs = _append_quantized(cache["v"], cache["vs"], v_new, loc, off)
-        cache = {"k": kp, "v": vp, "ks": ks, "vs": vs}
+    return jnp.where(owned, loc, NB_loc), pos % BS, owned
+
+
+def _fold_current(o, m, l, q, k_cur, v_cur, owned, k_scale=None,
+                  v_scale=None):
+    """Fold the current token into a slot's online-softmax partials as one
+    more term (m = q.k * scale, l = 1, o = v) with the T4 rescale rule
+    `merge_partials` applies.  q: [B, H, D]; k/v_cur: [B, KV, D], the
+    values as the pool stores them (int8 with `k_scale`/`v_scale` [B, KV],
+    applied as the kernel applies a block's scale); owned [B]: the shard
+    that holds the token's block counts it, every other shard adds
+    nothing.  Elementwise fp32 math, so the term rounds like the kernel's
+    fp32 dot over the same stored values."""
+    B, H, D = q.shape
+    KV = k_cur.shape[1]
+    qf = q.astype(jnp.float32).reshape(B, KV, H // KV, D)
+    s = jnp.sum(qf * k_cur.astype(jnp.float32)[:, :, None, :], axis=-1)
+    s = s * float(1.0 / (D ** 0.5))
+    if k_scale is not None:
+        s = s * k_scale[:, :, None]
+    v = v_cur.astype(jnp.float32)
+    if v_scale is not None:
+        v = v * v_scale[..., None]
+    v = jnp.broadcast_to(v[:, :, None, :], (B, KV, H // KV, D))
+    s = jnp.where(owned[:, None, None], s, NEG_INF).reshape(B, H)
+    m_all = jnp.maximum(m, s)
+    a = jnp.exp(m - m_all)
+    b = jnp.exp(s - m_all) * owned[:, None]
+    o = o * a[..., None] + b[..., None] * v.reshape(B, H, D)
+    return o, m_all, l * a + b
+
+
+def attn_decode_paged(p, x, pos, pool, layer, block_tables, *, plan: Plan,
+                      cfg, policy: Policy, norm=None, residual=None):
+    """One decode step of one layer against the block-paged KV cache
+    (full-context layers only — window/ring layers keep the dense per-slot
+    ring, `attn_decode`).
+
+    x: [B, E]; pos: [B] — position index of the token being written;
+    pool: {"k","v"[,"ks","vs"]} every layer's pool shards, stacked
+    ([count, NB_loc, BS, KV*hd]; int8 scales [count, NB_loc, KV]),
+    block-sharded over `plan.cache_axes` and read only; layer: int32
+    scalar, this layer's index in the stack; block_tables: [B, MB] int32
+    *global* pool indices in sequence order (< 0 = unallocated).
+    Returns (y [B, E], rows): rows holds this token's K/V as the pool will
+    store them ({"k","v"} [B, KV*hd] in the pool dtype; int8 pools add
+    {"ks","vs"} [B, KV], the scales a block first written here takes),
+    which `write_decode_rows` puts into the pool after the layer scan.
+
+    Attention dispatches to the paged split-KV partials kernel
+    (kernels/ops.paged_decode_partials), which reads the stacked pool in
+    place over the positions before `pos` in the blocks this shard owns;
+    the current token joins as one more online-softmax term on the shard
+    that owns its block, and the per-shard partials merge across cache
+    shards with the same T4 rule as the dense path — the pool is never
+    gathered, sliced or copied."""
+    c_ax = plan.cache_axes
+    B, E = x.shape
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    ad = act_dtype(policy)
+
+    NB_loc, BS = pool["k"].shape[1], pool["k"].shape[2]
+    start = col.axis_index(c_ax) * NB_loc          # first owned global block
+
+    q = _decode_q(p, x, pos, plan=plan, cfg=cfg, policy=policy,
+                  norm=norm).astype(ad)
+    k_new, v_new = _decode_kv_new(p, x, pos, plan=plan, cfg=cfg,
+                                  policy=policy, norm=norm)
+
+    loc, off, owned = _decode_slot(pos, block_tables, NB_loc, BS, plan)
+    scales = {}
+    if _quantized_kv(pool):
+        rows, read = {}, {}
+        for key, x_new in (("k", k_new), ("v", v_new)):
+            s_old = pool[key + "s"][layer, jnp.clip(loc, 0, NB_loc - 1)]
+            rows[key], rows[key + "s"], read[key] = _quantize_row(
+                x_new, s_old, off == 0)
+        k_cur, v_cur = rows["k"], rows["v"]
+        scales = {"k_scale": read["k"], "v_scale": read["v"]}
     else:
-        cache = {
-            "k": cache["k"].at[loc, off].set(
-                k_new.astype(cache["k"].dtype), mode="drop"),
-            "v": cache["v"].at[loc, off].set(
-                v_new.astype(cache["v"].dtype), mode="drop"),
-        }
+        dt = pool["k"].dtype
+        k_cur, v_cur = k_new.astype(dt), v_new.astype(dt)
+        rows = {"k": k_cur, "v": v_cur}
+    rows["k"] = rows["k"].reshape(B, KV * hd)
+    rows["v"] = rows["v"].reshape(B, KV * hd)
 
     # local view of the table: entries this shard owns, local ids
-    length = pos + 1                               # incl. the token just cached
     loc_tab = block_tables - start
     present = (block_tables >= 0) & (loc_tab >= 0) & (loc_tab < NB_loc)
     loc_tab = jnp.where(present, loc_tab, -1)
 
-    o, m, l = ops.paged_decode_partials(q.astype(ad), cache["k"], cache["v"],
-                                        loc_tab, length,
-                                        k_scale=cache.get("ks"),
-                                        v_scale=cache.get("vs"))
+    o, m, l = ops.paged_decode_partials(q, pool["k"], pool["v"], layer,
+                                        loc_tab, pos,
+                                        k_scale=pool.get("ks"),
+                                        v_scale=pool.get("vs"))
+    o, m, l = _fold_current(o, m, l, q, k_cur, v_cur, owned, **scales)
     merged = merge_partials(o, m, l, c_ax).reshape(B, H * hd)  # T4 merge
     return _decode_out_proj(p, merged, plan=plan, policy=policy,
-                            residual=residual), cache
+                            residual=residual), rows
+
+
+def write_decode_rows(pool, rows, pos, block_tables, *, plan: Plan):
+    """Write one decode step's new tokens into the pools, every layer at
+    once: rows {"k","v"} [count, B, KV*hd] (int8 pools add {"ks","vs"}
+    [count, B, KV]), stacked by the decode layer scan from
+    `attn_decode_paged`.  One scatter per leaf lands row [l, b] in layer
+    l's block table[b, pos // BS] at offset pos % BS, in place in the
+    donated pool; absent / non-owned blocks are dropped.  A row at offset 0
+    is its block's first: its scale replaces the block's (the append rule
+    of `_quantize_row`).  -> the updated pool leaves."""
+    count, NB_loc, BS = pool["k"].shape[:3]
+    loc, off, _ = _decode_slot(pos, block_tables, NB_loc, BS, plan)
+    lay = jnp.arange(count)[:, None]
+    out = {key: pool[key].at[lay, loc[None], off[None]].set(
+        rows[key], mode="drop") for key in ("k", "v")}
+    if _quantized_kv(pool):
+        sloc = jnp.where(off == 0, loc, NB_loc)[None]
+        for key in ("ks", "vs"):
+            out[key] = pool[key].at[lay, sloc].set(rows[key], mode="drop")
+    return out

@@ -329,13 +329,17 @@ def block_chunk(kind: str, p, x, pos0, chunk_len, cache, block_tables, *,
 
 
 def block_decode(kind: str, p, x, pos, cache, *, plan: Plan, cfg, policy,
-                 memory_len: int = 0, block_tables=None, paged: bool = False):
+                 memory_len: int = 0, block_tables=None, pool=None,
+                 layer=None):
     """x: [B, E]; pos: [B]; cache: this layer's cache dict.
     Returns (x', updated cache).
 
-    `paged`: this kind's self-attention KV lives in a block pool
-    ([NB, BS, KV, hd] leaves) addressed through `block_tables` [B, MB]
-    (core/attention.attn_decode_paged); SSM state, ring caches and
+    `pool` / `layer`: this kind's self-attention KV lives in the block
+    pools — every layer's, stacked ([count, NB, BS, KV*hd] leaves, read
+    only) — addressed through `block_tables` [B, MB]; `layer` is this
+    layer's index in the stack (core/attention.attn_decode_paged).  The
+    updated cache then carries the token's pool rows under "kv_rows" for
+    the caller to write after the layer scan.  SSM state, ring caches and
     cross-attention memory are per-slot dense either way."""
     window = kind_window(kind, cfg)
     new_cache = dict(cache)
@@ -351,7 +355,6 @@ def block_decode(kind: str, p, x, pos, cache, *, plan: Plan, cfg, policy,
 
     hybrid = kind in ("hybrid_attn", "hybrid_local")
     moe_like = kind in MOE_KINDS
-    kv_in = {k: cache[k] for k in ("k", "v", "ks", "vs") if k in cache}
     attn_fused = fused and not hybrid
     nspec = (ops.norm_prologue(p["ln1"], cfg.norm) if attn_fused else None)
     res = x if attn_fused and not moe_like else None
@@ -361,16 +364,16 @@ def block_decode(kind: str, p, x, pos, cache, *, plan: Plan, cfg, policy,
     else:
         h = ops.norm(x, p["ln1"], cfg.norm)
         q_in = h
-    if paged:
-        y, kv = attn.attn_decode_paged(p["attn"], q_in, pos, kv_in,
-                                       block_tables, plan=plan, cfg=cfg,
-                                       policy=policy, norm=nspec,
-                                       residual=res)
+    if pool is not None:
+        y, new_cache["kv_rows"] = attn.attn_decode_paged(
+            p["attn"], q_in, pos, pool, layer, block_tables, plan=plan,
+            cfg=cfg, policy=policy, norm=nspec, residual=res)
     else:
-        y, kv = attn.attn_decode(p["attn"], q_in, pos, kv_in, plan=plan,
-                                 cfg=cfg, policy=policy, window=window,
-                                 norm=nspec, residual=res)
-    new_cache.update(kv)
+        y, kv = attn.attn_decode(p["attn"], q_in, pos,
+                                 {k: cache[k] for k in ("k", "v")},
+                                 plan=plan, cfg=cfg, policy=policy,
+                                 window=window, norm=nspec, residual=res)
+        new_cache.update(kv)
     if res is not None:         # y IS the updated stream
         x, y = y, None
     if hybrid:

@@ -11,10 +11,12 @@ so chip-local and pod-level softmax use one primitive.
 
 Grid: (B, n_chunks) with the chunk dim innermost; each step folds one
 chunk of every KV head and partials are merged in-kernel through VMEM
-scratch (single pass over the cache).  The caches are viewed as
-[..., KV*D] (a free reshape), which keeps every block's last two dims
-TPU-tileable — a per-head (1, chunk, 1, D) block is refused by the
-compiler.
+scratch (single pass over the cache).  A chunk is a [chunk, KV*D] tile:
+every block's last two dims stay TPU-tileable (a per-head (1, chunk, 1, D)
+block is refused by the compiler).  The dense caches are stored
+[B, S, KV, D], so `decode_attention` views them as [B, S, KV*D]; on the
+TPU that reshape changes the minor dims' tiling and costs a relayout copy
+of the cache (the dense path is not a serving hot path).
 
 `paged_decode_attention` is the block-paged variant of the same primitive:
 the cache is a global pool of fixed-size KV blocks and each slot owns an
@@ -25,7 +27,16 @@ table to DMA the named block, and absent entries (unallocated / non-owned
 shard) skip their fold — the grid still spans max_blocks cells per slot,
 but the dot work (and, via consecutive-index pipelining, the block
 fetches) tracks the blocks a slot actually owns, while the pool *capacity*
-is decoupled from B x max_seq entirely."""
+is decoupled from B x max_seq entirely.
+
+The paged kernels read the pool in the layout it is stored in: every
+layer's pools stacked, [L, NB, BS, KV*D] (launch/steps.cache_layout), one
+token row holding all heads side by side.  The layer index rides in as one
+more scalar-prefetch operand and the index map returns (layer, table[b, e],
+0, 0), so a decode layer scan passes the whole stacked pool to every layer's
+call and no per-layer slice, relayout or write-back of the pool is ever
+made (viewing a [.., KV, D] pool as [.., KV*D] is NOT free on the TPU: it
+changes the minor dims' tiling and copies the pool)."""
 from __future__ import annotations
 
 import functools
@@ -135,8 +146,9 @@ def decode_attention(q, k_cache, v_cache, length, *, window=0, block_kv=512,
                      interpret=False):
     """q: [B, H, D]; caches: [B, S, KV, D]; length: [B] or scalar valid
     lengths.  Returns [B, H, D].  Grid (B, chunks): each step folds one
-    chunk of every KV head (the cache rows are viewed as [B, S, KV*D], a
-    free reshape, so a chunk is one contiguous DMA)."""
+    chunk of every KV head (the cache rows are viewed as [B, S, KV*D], so
+    a chunk is one contiguous DMA; on the TPU the view is a relayout copy,
+    see the module docstring)."""
     B, H, D = q.shape
     _, S, KV, _ = k_cache.shape
     G = H // KV
@@ -196,13 +208,14 @@ def _paged_mask(tab_ref, len_ref, b, e, g: int, block_size: int):
 
 def _paged_scales(tab_ref, ks_ref, vs_ref, b, e, kv: int):
     """Per-head dequant scalars for this grid step's pool block — one SMEM
-    lookup per (block, head).  Absent entries (t < 0) read block 0's
-    scales; their fold is dead (`live` is False)."""
+    lookup per (block, head) in the flattened [NB*KV] scale tables.
+    Absent entries (t < 0) read block 0's scales; their fold is dead
+    (`live` is False)."""
     if ks_ref is None:
         return None, None
-    t = jnp.maximum(tab_ref[b, e], 0)
-    return ([ks_ref[t, h] for h in range(kv)],
-            [vs_ref[t, h] for h in range(kv)])
+    t = jnp.maximum(tab_ref[b, e], 0) * kv
+    return ([ks_ref[t + h] for h in range(kv)],
+            [vs_ref[t + h] for h in range(kv)])
 
 
 def _paged_fold(refs, quantized: bool, n_out: int, block_size: int,
@@ -210,12 +223,12 @@ def _paged_fold(refs, quantized: bool, n_out: int, block_size: int,
     """Unpack the paged kernels' refs and fold this step's pool block.
     -> (e, outs, m_ref, l_ref, acc_ref)."""
     if quantized:
-        tab_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref = refs[:7]
-        rest = refs[7:]
+        _, tab_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref = refs[:8]
+        rest = refs[8:]
     else:
-        tab_ref, len_ref, q_ref, k_ref, v_ref = refs[:5]
+        _, tab_ref, len_ref, q_ref, k_ref, v_ref = refs[:6]
         ks_ref = vs_ref = None
-        rest = refs[5:]
+        rest = refs[6:]
     outs, (m_ref, l_ref, acc_ref) = rest[:n_out], rest[n_out:]
     b = pl.program_id(0)
     e = pl.program_id(1)
@@ -231,11 +244,12 @@ def _paged_fold(refs, quantized: bool, n_out: int, block_size: int,
 def _paged_decode_kernel(*refs, block_size: int, sm_scale: float,
                          quantized: bool):
     """q_ref: [1, KV, G, D]; k/v_ref: [1, block_size, KV*D] — the pool
-    block the slot's table names for entry `e` (the index map dereferenced
-    it), all heads; tab_ref: scalar-prefetch [B, MB] block tables (< 0 =
-    absent); len_ref: scalar-prefetch [B] valid lengths.  Quantized pools
-    add ks/vs_ref: scalar-prefetch [NB, KV] fp32 per-block-per-head
-    scales."""
+    block the slot's table names for entry `e` in the layer the layer
+    operand names (the index map dereferenced both), all heads; scalar
+    prefetch: the layer index [1], tab_ref [B, MB] block tables (< 0 =
+    absent), len_ref [B] valid lengths.  Quantized pools add ks/vs_ref:
+    scalar-prefetch [NB*KV] fp32 per-block-per-head scales of that layer,
+    flattened."""
     e, (o_ref,), m_ref, l_ref, acc_ref = _paged_fold(
         refs, quantized, 1, block_size, sm_scale)
 
@@ -259,28 +273,35 @@ def _paged_partials_kernel(*refs, block_size: int, sm_scale: float,
         lo_ref[0] = l_ref[...]
 
 
-def _paged_call(kernel, q, k_pool, v_pool, block_tables, lengths, out_shape,
-                out_specs, interpret, k_scale=None, v_scale=None):
+def _paged_call(kernel, q, k_pool, v_pool, layer, block_tables, lengths,
+                out_shape, out_specs, interpret, k_scale=None, v_scale=None):
     """Shared pallas_call plumbing for the paged kernels: grid (slot, table
-    entry) with scalar-prefetched tables dereferenced by the k/v index maps
-    — each step DMAs exactly one owned pool block, every KV head at once
-    (the pool is viewed as [NB, BS, KV*D], a free reshape).  Int8 pools
-    additionally prefetch the [NB, KV] dequant scale tables."""
+    entry) with the layer index and the tables scalar-prefetched and
+    dereferenced by the k/v index maps — each step DMAs exactly one owned
+    pool block of one layer, every KV head at once, straight out of the
+    stacked pool [L, NB, BS, KV*D] as it is stored.  Int8 pools
+    additionally prefetch that layer's dequant scales, a [NB, KV] slice of
+    the [L, NB, KV] stack flattened to [NB*KV]: scalar memory is 1 MiB on
+    a v5e and pads a 2-D table's rows to 128 words, so neither the whole
+    stack nor a [NB, KV] table fits at serving pool sizes (1600 blocks)."""
     B, KV, G, D = q.shape
-    NB, BS, _, _ = k_pool.shape
+    _, NB, BS, KVD = k_pool.shape
+    assert KVD == KV * D, (k_pool.shape, q.shape)
     MB = block_tables.shape[1]
     sm_scale = float(1.0 / (D ** 0.5))
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     block_tables = block_tables.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     quantized = k_scale is not None
 
-    def kv_index(b, e, tab_ref, *_pref):
+    def kv_index(b, e, lay_ref, tab_ref, *_pref):
         t = tab_ref[b, e]
-        return (jnp.where(t < 0, 0, t), 0, 0)    # absent -> any block, masked
+        # absent -> any block, masked
+        return (lay_ref[0], jnp.where(t < 0, 0, t), 0, 0)
 
-    kv_spec = pl.BlockSpec((1, BS, KV * D), kv_index)
+    kv_spec = pl.BlockSpec((None, 1, BS, KVD), kv_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 if quantized else 2,
+        num_scalar_prefetch=5 if quantized else 3,
         grid=(B, MB),
         in_specs=[
             pl.BlockSpec((1, KV, G, D), lambda b, e, *_pref: (b, 0, 0, 0)),
@@ -289,18 +310,17 @@ def _paged_call(kernel, q, k_pool, v_pool, block_tables, lengths, out_shape,
         out_specs=out_specs,
         scratch_shapes=_scratch(KV, G, D),
     )
-    prefetch = (block_tables, lengths)
+    prefetch = (layer, block_tables, lengths)
     if quantized:
-        prefetch += (k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32))
+        prefetch += tuple(x[layer[0]].reshape(-1).astype(jnp.float32)
+                          for x in (k_scale, v_scale))
     return pl.pallas_call(
         functools.partial(kernel, block_size=BS, sm_scale=sm_scale,
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(*prefetch, q, k_pool.reshape(NB, BS, KV * D),
-      v_pool.reshape(NB, BS, KV * D))
+    )(*prefetch, q, k_pool, v_pool)
 
 
 def _slot_spec(*block):
@@ -308,21 +328,22 @@ def _slot_spec(*block):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                           k_scale=None, v_scale=None, interpret=False):
-    """Paged split-KV decode.  q: [B, H, D]; k/v_pool: [NB, BS, KV, D] —
-    global pool of fixed-size KV blocks; block_tables: [B, MB] int32 pool
+def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
+                           *, k_scale=None, v_scale=None, interpret=False):
+    """Paged split-KV decode.  q: [B, H, D]; k/v_pool: [L, NB, BS, KV*D] —
+    every layer's global pool of fixed-size KV blocks, stacked; layer:
+    int32 scalar, the layer to attend; block_tables: [B, MB] int32 pool
     indices in sequence order (< 0 = absent entry); lengths: [B] valid
     tokens per slot.  Returns [B, H, D], softmax fully normalized
     (single-pool case; sharded pools use `paged_decode_partials`).
 
-    `k_scale`/`v_scale` ([NB, KV] fp32): per-block-per-head dequant scales
-    for int8 pools (quantize-on-write lives in the cache scatters)."""
+    `k_scale`/`v_scale` ([L, NB, KV] fp32): per-block-per-head dequant
+    scales for int8 pools (quantize-on-write lives in the cache scatters)."""
     B, H, D = q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[-1] // D
     G = H // KV
     out = _paged_call(
-        _paged_decode_kernel, q.reshape(B, KV, G, D), k_pool, v_pool,
+        _paged_decode_kernel, q.reshape(B, KV, G, D), k_pool, v_pool, layer,
         block_tables, lengths,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         out_specs=_slot_spec(KV, G, D),
@@ -331,19 +352,20 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_partials(q, k_pool, v_pool, block_tables, lengths, *,
+def paged_decode_partials(q, k_pool, v_pool, layer, block_tables, lengths, *,
                           k_scale=None, v_scale=None, interpret=False):
     """Paged split-KV decode emitting unnormalized online-softmax partials:
-    -> (o [B, H, D] fp32 unnormalized, m [B, H], l [B, H]).  Each cache
+    -> (o [B, H, D] fp32 unnormalized, m [B, H], l [B, H]).  Operands as
+    `paged_decode_attention` (stacked pools, a layer index).  Each cache
     shard runs this over its *local* pool (non-owned table entries < 0) and
     the T4 merge rule combines the shards — the pool is never gathered."""
     B, H, D = q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[-1] // D
     G = H // KV
     stat = jax.ShapeDtypeStruct((B, KV, G, 1), jnp.float32)
     o, m, l = _paged_call(
         _paged_partials_kernel, q.reshape(B, KV, G, D), k_pool, v_pool,
-        block_tables, lengths,
+        layer, block_tables, lengths,
         out_shape=[jax.ShapeDtypeStruct((B, KV, G, D), jnp.float32),
                    stat, stat],
         out_specs=[_slot_spec(KV, G, D), _slot_spec(KV, G, 1),

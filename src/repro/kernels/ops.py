@@ -125,7 +125,7 @@ def _use_pallas() -> tuple[bool, bool]:
 # ops with a Pallas kernel, and those without one (the reference IS their
 # TPU path)
 KERNEL_OPS = ("flash_attention", "decode_attention", "paged_decode_attention",
-              "paged_decode_partials", "matmul", "matmul_swiglu",
+              "paged_decode_partials.stacked", "matmul", "matmul_swiglu",
               "fused_matmul", "fused_matmul_swiglu", "expert_swiglu",
               "residual_norm", "rmsnorm", "layernorm", "ssd")
 REF_ONLY = ("paged_chunk_partials", "ssd_decode")
@@ -198,42 +198,55 @@ def decode_attention(q, k_cache, v_cache, length, *, window=0, block_kv=512):
                                      window=window)
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                           k_scale=None, v_scale=None):
-    """Block-paged decode.  q: [B, H, D]; k/v_pool: [NB, BS, KV, D];
-    block_tables: [B, MB] int32 pool indices (< 0 = absent entry);
-    lengths: [B] valid tokens per slot.  Fully normalized output.
-    `k_scale`/`v_scale` ([NB, KV] fp32): dequant scales for int8 pools."""
+def paged_decode_attention(q, k_pool, v_pool, layer, block_tables, lengths,
+                           *, k_scale=None, v_scale=None):
+    """Block-paged decode.  q: [B, H, D]; k/v_pool: [L, NB, BS, KV*D]
+    stacked per-layer pools, as stored; layer: int32 scalar, the layer
+    attended; block_tables: [B, MB] int32 pool indices (< 0 = absent
+    entry); lengths: [B] valid tokens per slot.  Fully normalized output.
+    `k_scale`/`v_scale` ([L, NB, KV] fp32): dequant scales for int8
+    pools."""
     use, interp = _use_pallas()
     if _note("paged_decode_attention", use):
-        return _fd.paged_decode_attention(q, k_pool, v_pool, block_tables,
-                                          lengths, k_scale=k_scale,
-                                          v_scale=v_scale, interpret=interp)
-    return _ref.paged_decode_attention_ref(q, k_pool, v_pool, block_tables,
-                                           lengths, k_scale=k_scale,
-                                           v_scale=v_scale)
+        return _fd.paged_decode_attention(q, k_pool, v_pool, layer,
+                                          block_tables, lengths,
+                                          k_scale=k_scale, v_scale=v_scale,
+                                          interpret=interp)
+    k, v, ks, vs = _layer_of(layer, k_pool, v_pool, k_scale, v_scale)
+    return _ref.paged_decode_attention_ref(q, k, v, block_tables, lengths,
+                                           k_scale=ks, v_scale=vs)
 
 
-def paged_decode_partials(q, k_pool, v_pool, block_tables, lengths, *,
+def paged_decode_partials(q, k_pool, v_pool, layer, block_tables, lengths, *,
                           k_scale=None, v_scale=None):
     """Block-paged decode partials -> (o unnormalized [B, H, D] fp32,
     m [B, H], l [B, H]) for the cross-shard online-softmax merge
     (core/attention.merge_partials); same operands as
-    `paged_decode_attention`, run per cache shard on its local pool."""
+    `paged_decode_attention`, run per cache shard on its local pools.  The
+    kernel reads the stacked pools in place (no per-layer slice), and is
+    counted as "paged_decode_partials.stacked"."""
     use, interp = _use_pallas()
-    if _note("paged_decode_partials", use):
-        return _fd.paged_decode_partials(q, k_pool, v_pool, block_tables,
-                                         lengths, k_scale=k_scale,
-                                         v_scale=v_scale, interpret=interp)
-    return _ref.paged_decode_partials_ref(q, k_pool, v_pool, block_tables,
-                                          lengths, k_scale=k_scale,
-                                          v_scale=v_scale)
+    if _note("paged_decode_partials.stacked", use):
+        return _fd.paged_decode_partials(q, k_pool, v_pool, layer,
+                                         block_tables, lengths,
+                                         k_scale=k_scale, v_scale=v_scale,
+                                         interpret=interp)
+    k, v, ks, vs = _layer_of(layer, k_pool, v_pool, k_scale, v_scale)
+    return _ref.paged_decode_partials_ref(q, k, v, block_tables, lengths,
+                                          k_scale=ks, v_scale=vs)
+
+
+def _layer_of(layer, *stacked):
+    """One layer of each stacked [L, ...] operand (None passes): the
+    reference's view; the kernel indexes the stack in place."""
+    return tuple(None if x is None else x[layer] for x in stacked)
 
 
 def paged_chunk_partials(q, k_pool, v_pool, block_tables, q_pos, lengths, *,
                          k_scale=None, v_scale=None, tree_mask=None):
     """Chunked-prefill partials -> (o unnormalized [B, C, H, D] fp32,
-    m [B, C, H], l [B, C, H]); q_pos [B, C] gives each query's absolute
+    m [B, C, H], l [B, C, H]) over one layer's pools [NB, BS, KV*D]
+    (scales [NB, KV]); q_pos [B, C] gives each query's absolute
     position for causal masking.  Run per cache shard on its local pool,
     merged with core/attention.merge_partials like the decode partials.
     `tree_mask` ([B, C, C] bool, optional) switches the intra-chunk mask

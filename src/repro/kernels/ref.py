@@ -165,21 +165,25 @@ def decode_attention_ref(q, k_cache, v_cache, length, *, window=0,
     return out.reshape(B, H, D).astype(out_dtype)
 
 
-def _paged_gather(k_pool, v_pool, block_tables, lengths,
+def _paged_gather(k_pool, v_pool, block_tables, lengths, head_dim: int,
                   k_scale=None, v_scale=None):
     """Dereference block tables into a dense [B, MB*BS, KV, D] view plus a
     [B, MB*BS] validity mask (token t of entry e = absolute position
-    e*BS + t; entries < 0 are absent).
+    e*BS + t; entries < 0 are absent).  Pools are one layer's
+    [NB, BS, KV*D], as stored (launch/steps.cache_layout): the gather comes
+    first, so only the gathered rows are split into heads.
 
     `k_scale`/`v_scale` ([NB, KV] fp32): per-block-per-head dequant scales
     for int8 pools — the single dequant hook for the quantized paged KV
     path (the Pallas kernel applies the same scalar per grid step)."""
-    _, BS, KV, D = k_pool.shape
+    _, BS, KVD = k_pool.shape
+    D = head_dim
+    KV = KVD // D
     B, MB = block_tables.shape
     present = block_tables >= 0                                  # [B, MB]
     tab = jnp.where(present, block_tables, 0)
-    k = k_pool.astype(jnp.float32)[tab]                  # [B, MB, BS, KV, D]
-    v = v_pool.astype(jnp.float32)[tab]
+    k = k_pool[tab].reshape(B, MB, BS, KV, D).astype(jnp.float32)
+    v = v_pool[tab].reshape(B, MB, BS, KV, D).astype(jnp.float32)
     if k_scale is not None:
         k = k * k_scale[tab][:, :, None, :, None]
     if v_scale is not None:
@@ -206,14 +210,14 @@ def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths, *,
                                k_scale=None, v_scale=None, out_dtype=None):
     """Paged single-token decode oracle (block-paged KV cache).
 
-    q: [B, H, D]; k/v_pool: [NB, BS, KV, D] — a global pool of fixed-size
-    KV blocks; block_tables: [B, MB] int32 block ids per slot in sequence
+    q: [B, H, D]; k/v_pool: [NB, BS, KV*D] — one layer's global pool of
+    fixed-size KV blocks, each token row holding every head; block_tables: [B, MB] int32 block ids per slot in sequence
     order (entries < 0 are absent: unallocated, or owned by another cache
     shard); lengths: [B] valid tokens per slot.  Gathers the table into a
     dense cache and defers to the dense softmax — ground truth, not fast."""
     out_dtype = out_dtype or q.dtype
     B, H, D = q.shape
-    k, v, msk = _paged_gather(k_pool, v_pool, block_tables, lengths,
+    k, v, msk = _paged_gather(k_pool, v_pool, block_tables, lengths, D,
                               k_scale, v_scale)
     s = _paged_scores(q, k, msk)
     p = jax.nn.softmax(s, axis=-1)
@@ -227,7 +231,7 @@ def paged_decode_partials_ref(q, k_pool, v_pool, block_tables, lengths, *,
     -> (o [B, H, D] fp32, m [B, H], l [B, H]) for the cross-shard T4 merge
     (each shard passes its local pool; absent entries are masked)."""
     B, H, D = q.shape
-    k, v, msk = _paged_gather(k_pool, v_pool, block_tables, lengths,
+    k, v, msk = _paged_gather(k_pool, v_pool, block_tables, lengths, D,
                               k_scale, v_scale)
     s = _paged_scores(q, k, msk)
     m = s.max(axis=-1)
@@ -244,7 +248,7 @@ def paged_chunk_partials_ref(q, k_pool, v_pool, block_tables, q_pos,
     pool (which already holds this chunk's own KV rows), causal-masked per
     query position.
 
-    q: [B, C, H, D]; k/v_pool: [NB, BS, KV, D]; block_tables: [B, MB]
+    q: [B, C, H, D]; k/v_pool: [NB, BS, KV*D]; block_tables: [B, MB]
     (entries < 0 absent); q_pos: [B, C] absolute position of each query
     (pad queries may point past `lengths` — their outputs are garbage the
     caller discards); lengths: [B] valid tokens incl. this chunk.
@@ -258,7 +262,7 @@ def paged_chunk_partials_ref(q, k_pool, v_pool, block_tables, q_pos,
     A lower-triangular tree_mask reproduces the causal `pos <= q_pos` mask
     exactly (the degenerate single-branch chain)."""
     B, C, H, D = q.shape
-    k, v, msk = _paged_gather(k_pool, v_pool, block_tables, lengths,
+    k, v, msk = _paged_gather(k_pool, v_pool, block_tables, lengths, D,
                               k_scale, v_scale)
     KV = k.shape[2]
     scale = 1.0 / jnp.sqrt(D).astype(jnp.float32)

@@ -127,8 +127,10 @@ def default_policy(cfg: ModelConfig, mode: str) -> Policy:
 class PagedLayout:
     """Static description of a block-paged KV cache layout.
 
-    The pools are [count, num_blocks, block_size, KV, hd] per full-attention
-    segment (block dim sharded over `plan.cache_axes`); one [B, max_blocks]
+    The pools are [count, num_blocks, block_size, KV*hd] per full-attention
+    segment (block dim sharded over `plan.cache_axes`), a token row holding
+    every head side by side: the layout the paged decode kernel tiles, so
+    it reads the stacked pools in place.  One [B, max_blocks]
     block table addresses every layer — block b of a sequence lives in pool
     slot table[b] of EVERY paged segment.  `segments` marks which schedule
     entries are paged (window/ring, SSM and cross-attention caches stay
@@ -190,8 +192,8 @@ def cache_layout(cfg: ModelConfig, plan: Plan, global_batch: int,
             W = blocks.kind_cache_len(kind, cfg, max_seq)
             kv_dims = (None, "batch", "cache", None, None)
             if paged is not None and blocks.kind_paged(kind, cfg, max_seq):
-                shape = (count, paged.num_blocks, paged.block_size, KV, hd)
-                kv_dims = (None, "cache", None, None, None)
+                shape = (count, paged.num_blocks, paged.block_size, KV * hd)
+                kv_dims = (None, "cache", None, None)
                 d["k"] = jax.ShapeDtypeStruct(shape, kv_dtype)
                 d["v"] = jax.ShapeDtypeStruct(shape, kv_dtype)
                 dm["k"] = dm["v"] = kv_dims

@@ -24,6 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import attention as attn
 from repro.core import blocks
 from repro.core import collectives as col
 from repro.core.embedding import (ce_loss, embed_sequence, embed_token,
@@ -192,21 +193,37 @@ def _run_segments_prefill(params, x, *, plan, cfg, policy, max_seq,
     return x, tuple(caches)
 
 
+POOL_KEYS = ("k", "v", "ks", "vs")
+
+
 def _run_segments_decode(params, x, pos, caches, *, plan, cfg, policy,
                          memory_len, block_tables=None, paged_segments=None):
+    """Paged segments keep their KV pools out of the layer scan: the
+    stacked pools are a read-only operand of every layer (the kernel reads
+    them in place by layer index), each layer emits its token's K/V rows,
+    and one scatter per pool leaf writes them after the scan — the decode
+    step moves no pool bytes but the blocks it reads and the rows it
+    writes.  Dense leaves (SSM state, rings, cross-attention memory) ride
+    the scan as before."""
     new_caches = []
     paged_segments = paged_segments or (False,) * len(cfg.schedule)
-    for (kind, _), p_seg, c_seg, pgd in zip(cfg.schedule, params["segments"],
-                                            caches, paged_segments):
-        def body(h, inp, _kind=kind, _paged=pgd):
-            p_layer, c_layer = inp
-            h2, c2 = blocks.block_decode(_kind, p_layer, h, pos, c_layer,
-                                         plan=plan, cfg=cfg, policy=policy,
-                                         memory_len=memory_len,
-                                         block_tables=block_tables,
-                                         paged=_paged)
-            return h2, c2
-        x, c_new = jax.lax.scan(body, x, (p_seg, c_seg))
+    for (kind, count), p_seg, c_seg, pgd in zip(
+            cfg.schedule, params["segments"], caches, paged_segments):
+        pool = ({k: v for k, v in c_seg.items() if k in POOL_KEYS}
+                if pgd else None)
+        dense = {k: v for k, v in c_seg.items() if k not in (pool or ())}
+
+        def body(h, inp, _kind=kind, _pool=pool):
+            p_layer, layer, c_layer = inp
+            return blocks.block_decode(_kind, p_layer, h, pos, c_layer,
+                                       plan=plan, cfg=cfg, policy=policy,
+                                       memory_len=memory_len,
+                                       block_tables=block_tables,
+                                       pool=_pool, layer=layer)
+        x, c_new = jax.lax.scan(body, x, (p_seg, jnp.arange(count), dense))
+        if pgd:
+            c_new.update(attn.write_decode_rows(
+                pool, c_new.pop("kv_rows"), pos, block_tables, plan=plan))
         new_caches.append(c_new)
     return x, tuple(new_caches)
 

@@ -184,6 +184,8 @@ def _prefill_scatter(caches, group_caches, slots, tables, *,
                 if pad:
                     val = jnp.pad(
                         val, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+                # [count, n, ne, BS, KV, hd]: heads split for the scales,
+                # flattened into the pool's [.., BS, KV*hd] rows on write
                 val = val.reshape(val.shape[0], n, ne, block_size,
                                   *val.shape[3:])
                 ids = tables[:, :ne]
@@ -200,12 +202,14 @@ def _prefill_scatter(caches, group_caches, slots, tables, *,
                     s = jnp.maximum(amax, SCALE_EPS) / 127.0
                     q = jnp.clip(jnp.round(xf / s[:, :, :, None, :, None]),
                                  -127, 127).astype(jnp.int8)
-                    d[key] = leaf.at[:, ids].set(q, mode="drop")
+                    d[key] = leaf.at[:, ids].set(q.reshape(*q.shape[:4], -1),
+                                                 mode="drop")
                     sk = key + "s"
                     d[sk] = seg[sk].at[:, ids].set(s, mode="drop")
                 else:
-                    d[key] = leaf.at[:, ids].set(val.astype(leaf.dtype),
-                                                 mode="drop")
+                    d[key] = leaf.at[:, ids].set(
+                        val.reshape(*val.shape[:4], -1).astype(leaf.dtype),
+                        mode="drop")
             else:
                 d[key] = leaf.at[:, slots].set(val.astype(leaf.dtype))
         out.append(d)
@@ -218,7 +222,7 @@ def make_prefill_scatter(paged_segments, block_size: int):
     scatter(caches, group_caches, slots, tables) -> caches
 
       caches        the live decode cache pytree (paged segments: k/v pools
-                    [count, NB, BS, KV, hd]; donated — updated in place)
+                    [count, NB, BS, KV*hd]; donated — updated in place)
       group_caches  a prefilled admission group's compact caches (paged
                     leaves [count, nB, S, KV, hd] at prompt length; dense
                     leaves [count, nB, ...])
@@ -246,7 +250,7 @@ def _block_copy(caches, src, dst, *, paged_segments):
             for key in ("k", "v", "ks", "vs"):
                 if key not in d:
                     continue
-                leaf = d[key]       # pools [count, NB, BS, KV, hd];
+                leaf = d[key]       # pools [count, NB, BS, KV*hd];
                 #                     scales [count, NB, KV] — dim 1 is NB
                 #                     for both, so one copy path serves them
                 row = jax.lax.dynamic_index_in_dim(leaf, src, axis=1,
@@ -288,13 +292,12 @@ def _row_copy(caches, src_blk, src_off, dst_blk, dst_off, *, paged_segments):
                 #                         cannot move one row at a time
                 if key not in d:
                     continue
-                leaf = d[key]           # [count, NB, BS, KV, hd]
-                count, _, _, kv, hd = leaf.shape
+                leaf = d[key]           # [count, NB, BS, KV*hd]
+                count, _, _, width = leaf.shape
                 row = jax.lax.dynamic_slice(
-                    leaf, (0, src_blk, src_off, 0, 0),
-                    (count, 1, 1, kv, hd))
+                    leaf, (0, src_blk, src_off, 0), (count, 1, 1, width))
                 d[key] = jax.lax.dynamic_update_slice(
-                    leaf, row, (0, dst_blk, dst_off, 0, 0))
+                    leaf, row, (0, dst_blk, dst_off, 0))
         out.append(d)
     return tuple(out)
 

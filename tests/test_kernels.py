@@ -305,7 +305,7 @@ def test_seq_sp_steps_on_one_device_dispatch_no_reference(monkeypatch):
             jax.eval_shape(step.fn, *step.in_structs)
         rec = ops.dispatch_record()
         assert rec["flash_attention"] == {"pallas": 1, "ref": 0}
-        assert rec["paged_decode_partials"]["pallas"] == 1
+        assert rec["paged_decode_partials.stacked"]["pallas"] == 1
         assert ops.hidden_fallbacks() == []
     finally:
         ops.reset_dispatch_record()

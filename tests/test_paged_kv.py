@@ -72,8 +72,8 @@ def test_allocator_blocks_for():
 
 def _paged_from_dense(dense_k, dense_v, lengths, *, num_blocks, block_size,
                       seed=0):
-    """Scatter a dense [B, S, KV, D] cache into a shuffled block pool +
-    per-slot tables (absent entries -1)."""
+    """Scatter a dense [B, S, KV, D] cache into a shuffled block pool
+    [NB, BS, KV*D] + per-slot tables (absent entries -1)."""
     rng = np.random.default_rng(seed)
     B, S, KV, D = dense_k.shape
     MB = -(-S // block_size)
@@ -89,7 +89,18 @@ def _paged_from_dense(dense_k, dense_v, lengths, *, num_blocks, block_size,
             k_pool[blk, :len(sl)] = sl
             sl = dense_v[b, e * block_size:(e + 1) * block_size]
             v_pool[blk, :len(sl)] = sl
-    return k_pool, v_pool, tables
+    flat = (num_blocks, block_size, KV * D)
+    return k_pool.reshape(flat), v_pool.reshape(flat), tables
+
+
+def _stacked(pool, layer, seed=0):
+    """`pool` as layer `layer` of a 3-layer stack whose other layers hold
+    noise: a kernel that reads the wrong layer cannot pass."""
+    rng = np.random.default_rng(seed)
+    layers = [rng.standard_normal(pool.shape).astype(pool.dtype)
+              for _ in range(3)]
+    layers[layer] = pool
+    return jnp.asarray(np.stack(layers))
 
 
 @pytest.mark.parametrize("B,H,KV,D,BS,lengths", [
@@ -118,8 +129,8 @@ def test_paged_decode_matches_dense_oracle(B, H, KV, D, BS, lengths):
     np.testing.assert_allclose(np.asarray(got_ref), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     got_kernel = paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tab),
-        lengths, interpret=True)
+        jnp.asarray(q), _stacked(kp, 1), _stacked(vp, 1, seed=1), 1,
+        jnp.asarray(tab), lengths, interpret=True)
     np.testing.assert_allclose(np.asarray(got_kernel), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -184,7 +195,7 @@ def test_paged_decode_ops_dispatch_interpret():
     dv = rng.standard_normal((B, S, KV, D)).astype(np.float32)
     kp, vp, tab = _paged_from_dense(dk, dv, lengths, num_blocks=NB,
                                     block_size=BS)
-    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+    args = (jnp.asarray(q), _stacked(kp, 2), _stacked(vp, 2, seed=1), 2,
             jnp.asarray(tab), jnp.asarray(np.asarray(lengths, np.int32)))
     with ops.kernel_mode("ref"):
         a = ops.paged_decode_attention(*args)
@@ -373,3 +384,228 @@ def test_window_arch_keeps_dense_ring_and_frees_blocks():
     for req in done:
         assert _direct_tokens(cfg, params, req.prompt, 3) == req.output
     assert engine.allocator.num_free == engine.allocator.num_blocks
+
+
+# --------------------------------------------------------------------------
+# decode: the stacked pool is read in place, the new rows written after
+# --------------------------------------------------------------------------
+
+def _decode_layer_case(kv_dtype):
+    """A two-layer stacked pool (int8: quantized per block and head) and a
+    batch of two slots: slot 0's token opens a block (offset 0), slot 1's
+    lands mid-block."""
+    cfg, params = _phi4()
+    KV, hd, BS, NB, L = cfg.n_kv_heads, cfg.head_dim, 4, 8, 2
+    rng = np.random.default_rng(61)
+    raw = [rng.standard_normal((L, NB, BS, KV, hd)).astype(np.float32)
+           for _ in range(2)]
+    if kv_dtype == "int8":
+        pool = {}
+        for key, x in zip(("k", "v"), raw):
+            s = np.maximum(np.abs(x).max(axis=(2, 4)), 1e-30) / 127.0
+            q = np.clip(np.round(x / s[:, :, None, :, None]), -127, 127)
+            pool[key] = jnp.asarray(q.reshape(L, NB, BS, KV * hd), jnp.int8)
+            pool[key + "s"] = jnp.asarray(s, jnp.float32)
+    else:
+        pool = {key: jnp.asarray(x.reshape(L, NB, BS, KV * hd), jnp.bfloat16)
+                for key, x in zip(("k", "v"), raw)}
+    tables = jnp.asarray([[3, 5, -1, -1], [0, 6, 2, -1]], jnp.int32)
+    pos = jnp.asarray([4, 9], jnp.int32)
+    x = jnp.asarray(rng.standard_normal((2, cfg.d_model)), jnp.float32)
+    p = jax.tree.map(lambda a: a[1], params["segments"][0])["attn"]
+    return cfg, p, pool, tables, pos, x
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+def test_decode_layer_matches_write_then_attend(kv_dtype, mode):
+    """One layer of the decode scan — attention over the positions before
+    the token with the token folded in as one more online-softmax term,
+    then the row written after the scan — equals the order it replaces:
+    the token written into its block first (int8: an offset-0 row re-amaxes
+    its block, a later one reuses the stored scale and clips), then every
+    position up to it attended."""
+    from repro.core import attention as attn
+    cfg, p, pool, tables, pos, x = _decode_layer_case(kv_dtype)
+    B, KV, hd = 2, cfg.n_kv_heads, cfg.head_dim
+    kw = dict(plan=UNSHARDED, cfg=cfg, policy=FP32)
+    with ops.kernel_mode(mode):
+        y, rows = attn.attn_decode_paged(p, x, pos, pool, 1, tables, **kw)
+    stacked = jax.tree.map(lambda r: jnp.stack([r, r]), rows)
+    written = attn.write_decode_rows(pool, stacked, pos, tables,
+                                     plan=UNSHARDED)
+
+    # the rows as the parent's quantize-on-write stores them
+    k_new, v_new = attn._decode_kv_new(p, x, pos, **kw)
+    blk, off = np.asarray([5, 2]), np.asarray([0, 1])
+    for key, new in (("k", k_new), ("v", v_new)):
+        new = np.asarray(new, np.float32)
+        row = np.asarray(written[key][1, blk, off], np.float32)
+        if kv_dtype == "int8":
+            s = np.asarray(written[key + "s"][1, blk])           # [B, KV]
+            amax0 = np.maximum(np.abs(new[0]).max(-1), 1e-30) / 127.0
+            np.testing.assert_allclose(s[0], amax0, rtol=1e-6)  # re-amaxed
+            np.testing.assert_array_equal(s[1], pool[key + "s"][1, 2])
+            want = np.clip(np.round(new / s[:, :, None]), -127, 127)
+            np.testing.assert_array_equal(row.reshape(B, KV, hd), want)
+        else:
+            np.testing.assert_array_equal(
+                row, np.asarray(jnp.asarray(new.reshape(B, -1), jnp.bfloat16),
+                                np.float32))
+    # untouched elsewhere: only the two rows changed in each layer
+    changed = np.asarray(written["k"] != pool["k"]).any(axis=-1)
+    assert set(zip(*np.nonzero(changed))) <= {(l, b, o) for l in (0, 1)
+                                              for b, o in zip(blk, off)}
+
+    q = attn._decode_q(p, x, pos, **kw)
+    with ops.kernel_mode(mode):
+        o, m, l = ops.paged_decode_partials(
+            q, written["k"], written["v"], 1, tables, pos + 1,
+            k_scale=written.get("ks"), v_scale=written.get("vs"))
+    merged = attn.merge_partials(o, m, l, ()).reshape(B, -1)
+    want = attn._decode_out_proj(p, merged, plan=UNSHARDED, policy=FP32)
+    # the bf16 kernel rounds its softmax weights to bf16 for the P.V dot
+    # (2^-9 relative); the folded term keeps its weight in fp32
+    tol = 1e-3 if (mode, kv_dtype) == ("interpret", "bfloat16") else 2e-5
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+def _serve_modes(cfg, params, prompts, mode, **kw):
+    """Serve `prompts` (uid 1 sampled, the rest greedy) in blocks of 4 under
+    kernel mode `mode` -> {uid: tokens}."""
+    with ops.kernel_mode(mode):
+        engine = InferenceEngine(cfg, params, batch_size=2, max_seq=32,
+                                 policy=FP32, block_size=4,
+                                 prefix_cache=False, **kw)
+        for uid, pr in enumerate(prompts):
+            engine.submit(Request(
+                uid=uid, prompt=pr, max_new_tokens=10,
+                sampling=(SamplingParams(temperature=0.8, top_k=8, seed=uid)
+                          if uid == 1 else SamplingParams())))
+        return {r.uid: r.output for r in engine.run()}
+
+
+def _parts_at_near_tie(cfg, params, prompt, got, want, rtol=2e-3):
+    """True when greedy streams `got` and `want` are equal, or part where
+    the float32 forward over the common prefix ranks both tokens within
+    `rtol` of the largest |logit| — a near-tie that two arithmetic orders
+    (the kernel rounds its softmax weights to bf16) may break apart."""
+    j = next((i for i, (a, b) in enumerate(zip(got, want)) if a != b), None)
+    if j is None:
+        return len(got) == len(want)
+    seq = np.concatenate([prompt, want[:j]]).astype(np.int32)
+    lg = np.asarray(lm.prefill_logits(params, {"tokens": jnp.asarray(seq)[None]},
+                                      plan=UNSHARDED, cfg=cfg,
+                                      policy=FP32)).reshape(-1)
+    return abs(lg[got[j]] - lg[want[j]]) <= rtol * np.abs(lg).max()
+
+
+def test_stacked_decode_kernel_matches_reference_across_blocks():
+    """Ten decode steps in blocks of 4 (every slot crosses two or three
+    block boundaries): the reference path serves the greedy requests the
+    dense-cache oracle serves (which never touches a pool), and the paged
+    kernel in interpret mode serves the reference's tokens — the sampled
+    request exactly, a greedy one up to a near-tie of the float32 forward
+    (this trace holds one: 0.38145 against 0.38107)."""
+    cfg, params = _phi4()
+    rng = np.random.default_rng(67)
+    prompts = [rng.integers(0, cfg.vocab, n, dtype=np.int32)
+               for n in (5, 11, 3)]
+    want = _serve_modes(cfg, params, prompts, "ref")
+    for uid in (0, 2):
+        assert want[uid] == _direct_tokens(cfg, params, prompts[uid], 10)
+    got = _serve_modes(cfg, params, prompts, "interpret")
+    assert got[1] == want[1]
+    for uid in (0, 2):
+        assert _parts_at_near_tie(cfg, params, prompts[uid], got[uid],
+                                  want[uid]), (uid, got[uid], want[uid])
+
+
+def test_decode_step_dispatches_stacked_kernel_per_paged_segment(
+        monkeypatch):
+    """Traced as if on a TPU, the paged decode step dispatches the in-place
+    form of the kernel once per paged segment (one scan body each), and
+    nothing takes the reference."""
+    from repro.configs.base import ShapeConfig
+    from repro.launch import steps
+    cfg = get_config("gemma3-27b").reduced()       # local and global segments
+    step = steps.make_decode_step(
+        cfg, ShapeConfig("d", "decode", 64, 2), None, max_seq=64,
+        with_sampling=True, paged=(16, 8))
+    n_paged = sum(step.aux["paged"].segments)
+    assert 0 < n_paged < len(cfg.schedule)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    ops.reset_dispatch_record()
+    try:
+        jax.eval_shape(step.fn, *step.in_structs)
+        rec = ops.dispatch_record()
+        assert rec["paged_decode_partials.stacked"] == {"pallas": n_paged,
+                                                        "ref": 0}
+        assert ops.hidden_fallbacks() == []
+    finally:
+        ops.reset_dispatch_record()
+
+
+_SHARDED_PROG = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import sys
+sys.path.insert(0, %r)
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs import get_config
+from repro.core.precision import FP32
+from repro.launch.mesh import make_test_mesh
+from repro.models import lm
+from repro.serving import InferenceEngine, Request, SamplingParams
+
+for arch in ("chatglm3-6b", "phi4-mini-3.8b"):
+    cfg = get_config(arch).reduced()
+    params = lm.init_lm(jax.random.key(0), cfg, jnp.float32)
+    rng = np.random.default_rng(71)
+    prompts = [rng.integers(0, cfg.vocab, n, dtype=np.int32)
+               for n in (9, 14, 6)]
+    for kv in ("bfloat16", "int8"):
+        outs = []
+        for mesh in (None, make_test_mesh((1, 2))):
+            eng = InferenceEngine(cfg, params, batch_size=2, max_seq=32,
+                                  policy=FP32, block_size=4,
+                                  kv_pool_blocks=16, kv_dtype=kv,
+                                  prefix_cache=False, mesh=mesh)
+            if mesh is not None:
+                assert eng.runner.decode_step.plan.cache_shards == 2
+            for uid, p in enumerate(prompts):
+                eng.submit(Request(uid=uid, prompt=p, max_new_tokens=10,
+                    sampling=(SamplingParams(temperature=0.8, top_k=8,
+                                             seed=uid) if uid == 1
+                              else SamplingParams())))
+            outs.append({r.uid: r.output for r in eng.run()
+                         if r.uid != 1})
+            assert eng.allocator.num_free == eng.allocator.num_blocks
+        assert outs[0] == outs[1], (arch, kv, outs)
+        print("OK", arch, kv, flush=True)
+"""
+
+
+def test_block_sharded_pool_counts_current_token_once():
+    """A pool block-sharded over 2 CPU devices (the cache axis of a (1, 2)
+    mesh; blocks of 4 in a 16-block pool, so slots own blocks on both
+    shards and a slot's current token lies on one of them): every shard
+    attends its own blocks before the token, the owning shard alone folds
+    the token in, and the merged result serves the greedy tokens one
+    device serves — bf16 and int8 pools, head_tp and seq_sp attention.  A
+    token counted on both shards, or on neither, changes the softmax and
+    the tokens.  A sampled request shares every step; its draws are not
+    compared, since they depend on how the vocabulary is sharded (they
+    differ between the mesh and one device with the pool untouched)."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    p = subprocess.run([sys.executable, "-c", _SHARDED_PROG % src],
+                       capture_output=True, text=True, timeout=900, env=env)
+    assert p.returncode == 0, p.stderr[-3000:]
+    assert p.stdout.count("OK") == 4, p.stdout

@@ -23,7 +23,7 @@ import pytest
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.core.precision import FP32
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.flash_decode import (paged_decode_attention,
                                         paged_decode_partials)
 from repro.kernels.matmul import matmul, matmul_swiglu
@@ -91,14 +91,14 @@ def test_matmul_swiglu_int8_vs_ref():
 
 
 def _int8_pool(key, NB, BS, KV, D):
-    """An int8 pool + per-block-per-head scales, quantized the way the
-    cache scatters write them."""
+    """An int8 pool [NB, BS, KV*D] + per-block-per-head scales [NB, KV],
+    quantized the way the cache scatters write them."""
     x = _rand(key, (NB, BS, KV, D))
     amax = jnp.abs(x).max(axis=(1, 3))
     s = jnp.maximum(amax, 1e-30) / 127.0
     q = jnp.clip(jnp.round(x / s[:, None, :, None]), -127, 127
                  ).astype(jnp.int8)
-    return q, s
+    return q.reshape(NB, BS, KV * D), s
 
 
 @pytest.mark.parametrize("B,H,KV,D", [(2, 4, 4, 32), (3, 8, 2, 16)])
@@ -111,8 +111,9 @@ def test_paged_decode_int8_vs_ref(B, H, KV, D):
     tables = jnp.asarray(np.stack([rng.permutation(NB)[:MB]
                                    for _ in range(B)]).astype(np.int32))
     lengths = jnp.asarray([BS * MB, 5, 17][:B], jnp.int32)
-    out = paged_decode_attention(q, kq, vq, tables, lengths, k_scale=ks,
-                                 v_scale=vs, interpret=True)
+    out = paged_decode_attention(q, kq[None], vq[None], 0, tables, lengths,
+                                 k_scale=ks[None], v_scale=vs[None],
+                                 interpret=True)
     want = ref.paged_decode_attention_ref(q, kq, vq, tables, lengths,
                                           k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
@@ -125,8 +126,9 @@ def test_paged_partials_int8_vs_ref():
     q = _rand(2, (B, H, D))
     tables = jnp.asarray([[0, 2, -1], [5, -1, -1]], jnp.int32)
     lengths = jnp.asarray([11, 8], jnp.int32)
-    o, m, l = paged_decode_partials(q, kq, vq, tables, lengths, k_scale=ks,
-                                    v_scale=vs, interpret=True)
+    o, m, l = paged_decode_partials(q, kq[None], vq[None], 0, tables,
+                                    lengths, k_scale=ks[None],
+                                    v_scale=vs[None], interpret=True)
     ow, mw, lw = ref.paged_decode_partials_ref(q, kq, vq, tables, lengths,
                                                k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(m, mw, rtol=2e-5, atol=2e-5)
@@ -138,8 +140,8 @@ def test_int8_pool_matches_bf16_attention():
     """End-to-end quantize-then-attend: the int8 pool's output must sit
     within quantization error of attending over the original bf16 pool."""
     B, H, KV, D, NB, BS = 2, 4, 4, 32, 4, 8
-    k = _rand(0, (NB, BS, KV, D))
-    v = _rand(1, (NB, BS, KV, D))
+    k = _rand(0, (NB, BS, KV, D)).reshape(NB, BS, KV * D)
+    v = _rand(1, (NB, BS, KV, D)).reshape(NB, BS, KV * D)
     kq, ks = _int8_pool(0, NB, BS, KV, D)   # same draws as k/v above
     vq, vs = _int8_pool(1, NB, BS, KV, D)
     q = _rand(2, (B, H, D))
@@ -357,6 +359,23 @@ def test_kv_int8_engine_token_identity():
     base = _run(cfg, params, mk(), prefix_cache=False)[1]
     _, got = _run(cfg, params, mk(), prefix_cache=False, kv_dtype="int8")
     assert got == base
+
+
+def test_kv_int8_stacked_decode_kernel_matches_reference():
+    """int8 pools, ten decode steps in blocks of 4 (each slot crosses two
+    or three block boundaries): the paged kernel in interpret mode serves
+    the jnp reference's tokens, greedy and sampled.  Both fold the current
+    token in as the pool stores it — quantized, then dequantized by its
+    block's scale (re-amaxed at offset 0, reused and clipped after)."""
+    cfg, params = _reduced("gpt-j")
+    outs = []
+    for mode in ("interpret", "ref"):
+        with ops.kernel_mode(mode):
+            outs.append(_run(cfg, params, _trace(cfg, pre_len=9, max_new=10,
+                                                 sampled=(1, 3)),
+                             prefix_cache=False, kv_dtype="int8",
+                             block_size=4)[1])
+    assert outs[0] == outs[1]
 
 
 def test_quantized_pool_cow_and_preemption():

@@ -105,13 +105,13 @@ def test_fused_matmul_swiglu_compiles(compile_for_tpu, M, wdt):
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
 def test_paged_decode_compiles(compile_for_tpu, op, kv_dtype):
     fn = getattr(ops, op)
-    pool = _s((NB, BS, KV, D), jnp.dtype(kv_dtype))
-    args = [_s((B, H, D)), pool, pool, _s((B, MB), jnp.int32),
-            _s((B,), jnp.int32)]
+    pool = _s((2, NB, BS, KV * D), jnp.dtype(kv_dtype))
+    args = [_s((B, H, D)), pool, pool, _s((), jnp.int32),
+            _s((B, MB), jnp.int32), _s((B,), jnp.int32)]
     if kv_dtype == "int8":
-        scales = [_s((NB, KV), jnp.float32)] * 2
-        compile_for_tpu(lambda q, k, v, t, n, ks, vs: fn(
-            q, k, v, t, n, k_scale=ks, v_scale=vs), *args, *scales)
+        scales = [_s((2, NB, KV), jnp.float32)] * 2
+        compile_for_tpu(lambda q, k, v, i, t, n, ks, vs: fn(
+            q, k, v, i, t, n, k_scale=ks, v_scale=vs), *args, *scales)
     else:
         compile_for_tpu(fn, *args)
 
@@ -134,3 +134,62 @@ def test_rmsnorm_compiles(compile_for_tpu):
 def test_residual_rmsnorm_compiles(compile_for_tpu):
     compile_for_tpu(lambda x, y, g: ops.residual_norm(
         x, y, {"scale": g}, "rmsnorm"), _s((256, E)), _s((256, E)), _s((E,)))
+
+
+# the paged decode step at phi4 (KV 8) and chatglm3 (KV 2) widths, 2 layers:
+# a prime block count tells a pool-sized tensor from a weight (no weight
+# dimension is a multiple of it), and each pool outweighs the step's
+# temporaries (the per-layer weight slices; scalar memory bounds int8's
+# block count, see flash_decode._paged_call)
+STEP_BLOCKS = {"phi4-mini-3.8b": 4099, "chatglm3-6b": 32749}
+MOVES = ("copy", "reshape", "transpose", "dynamic-slice",
+         "dynamic-update-slice")
+
+
+def _moved_pools(hlo: str, nb: int, layer_elems: int) -> list:
+    """Instructions of the optimized HLO that copy, relayout, slice or
+    update-slice a tensor of at least one layer's pool: by opcode, or by a
+    fusion's name (`bitcast_dynamic-update-slice_fusion`, ...)."""
+    import re
+    out = []
+    for m in re.finditer(r"%([\w.\-]+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(",
+                         hlo):
+        name, dims, op = m.groups()
+        n = 1
+        for d in dims.split(","):
+            n *= int(d)
+        kind = name if op == "fusion" else op
+        if (n >= layer_elems and n % nb == 0
+                and any(re.search(rf"(^|_){w}(_|\.|-done|$)", kind)
+                        for w in MOVES)):
+            out.append(f"{name} = {op}[{dims}]")
+    return out
+
+
+@pytest.mark.parametrize("arch", list(STEP_BLOCKS))
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_paged_decode_step_moves_no_pool(compile_for_tpu, topo, arch,
+                                         kv_dtype):
+    """The decode step, as the engine builds it on one chip, moves no pool
+    bytes but the blocks its kernel reads and the rows it writes: no copy,
+    relayout, slice or write-back of a pool-sized tensor, and temporaries
+    under one pool (the step used to hold a second copy of both)."""
+    import dataclasses
+    import numpy as np
+    from jax.sharding import Mesh
+    from repro.configs import get_config
+    from repro.configs.base import ShapeConfig, uniform_schedule
+    from repro.launch import steps
+    nb = STEP_BLOCKS[arch]
+    cfg = dataclasses.replace(get_config(arch), n_layers=2, vocab=4096,
+                              schedule=uniform_schedule("attn", 2))
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    step = steps.make_decode_step(
+        cfg, ShapeConfig("d", "decode", 1024, 8), mesh, max_seq=1024,
+        with_sampling=True, paged=(nb, BS), kv_cache_dtype=kv_dtype)
+    with ops.kernel_mode("pallas"):
+        compiled = step.fn.lower(*step.in_structs).compile()
+    layer_elems = nb * BS * cfg.n_kv_heads * cfg.head_dim
+    assert not _moved_pools(compiled.as_text(), nb, layer_elems)
+    pool_bytes = 2 * layer_elems * jnp.dtype(kv_dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes

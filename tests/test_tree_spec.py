@@ -105,8 +105,8 @@ def test_accept_tree_path():
 
 def _chunk_inputs(rng, B, C, pos0, *, H=4, D=8, KV=2, BS=4, NB=8, MB=4):
     q = jnp.asarray(rng.normal(size=(B, C, H, D)), jnp.float32)
-    k_pool = jnp.asarray(rng.normal(size=(NB, BS, KV, D)), jnp.float32)
-    v_pool = jnp.asarray(rng.normal(size=(NB, BS, KV, D)), jnp.float32)
+    k_pool = jnp.asarray(rng.normal(size=(NB, BS, KV * D)), jnp.float32)
+    v_pool = jnp.asarray(rng.normal(size=(NB, BS, KV * D)), jnp.float32)
     tables = jnp.asarray(
         [[b * MB + i for i in range(MB)] for b in range(B)], jnp.int32)
     q_pos = jnp.asarray(np.asarray(pos0)[:, None] + np.arange(C)[None, :],
